@@ -1,9 +1,9 @@
-"""End-to-end serving driver (deliverable b): a REAL JAX model served with
-batched multi-priority requests through the full ProServe stack —
-SlideBatching, paged KV pool, chunked prefill, paged flash-decode
-(Pallas kernels in interpret mode on CPU), priority preemption with host
-offload/reload — and verifies outputs against uninterrupted greedy
-generation.
+"""End-to-end serving driver: the smoke-scale (2-layer, 64-wide) Qwen1.5
+config served with batched multi-priority requests through the full
+ProServe stack — SlideBatching, paged KV pool, chunked prefill, paged
+flash-decode, priority preemption with host offload/reload — and verified
+against uninterrupted greedy generation.  On CPU the Pallas kernels run
+in interpret mode; ``chip_smoke.py`` is the full-width run on a TPU.
 
     PYTHONPATH=src python examples/priority_serving.py
 """

@@ -21,24 +21,27 @@ def _kernel(idx_ref, pool_ref, out_ref):
 
 
 def block_gather(pool, indices, *, interpret: bool = False):
-    """pool: (P, page, Hkv, hd); indices: (n,) int32 -> (n, page, Hkv, hd)."""
+    """pool: (P, *page_shape), e.g. head-major ``(P, Hkv, page, hd)``;
+    indices: (n,) int32 -> (n, *page_shape).  The block is one whole page,
+    so it tiles for any page shape."""
     n = indices.shape[0]
-    _, page, hkv, hd = pool.shape
+    page_shape = pool.shape[1:]
+    rest = (0,) * len(page_shape)
 
     def in_map(i, idx):
-        return (idx[i], 0, 0, 0)
+        return (idx[i],) + rest
 
     def out_map(i, idx):
-        return (i, 0, 0, 0)
+        return (i,) + rest
 
     return pl.pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n,),
-            in_specs=[pl.BlockSpec((1, page, hkv, hd), in_map)],
-            out_specs=pl.BlockSpec((1, page, hkv, hd), out_map),
+            in_specs=[pl.BlockSpec((1,) + page_shape, in_map)],
+            out_specs=pl.BlockSpec((1,) + page_shape, out_map),
         ),
-        out_shape=jax.ShapeDtypeStruct((n, page, hkv, hd), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((n,) + page_shape, pool.dtype),
         interpret=interpret,
     )(indices, pool)

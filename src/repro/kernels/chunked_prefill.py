@@ -7,6 +7,9 @@ the cache (prefix) plus the chunk itself, causally.
 
   * grid = (batch, kv_head, kv_step): kv_step walks the cache in blocks,
     online softmax in VMEM scratch (flash);
+  * the staged cache is HEAD-MAJOR, ``(B, Hkv, Smax, hd)``, so one
+    (kv_block, hd) tile is the array's trailing two dims — the layout the
+    TPU compiler can tile for a one-head block;
   * the (G·Sq, kv_block) score tile keeps the MXU busy even for small
     chunks (G query heads per kv head stacked into rows);
   * per-request total lengths are scalar-prefetched; rows are masked
@@ -29,8 +32,8 @@ NEG_INF = -1e30
 def _kernel(
     lengths_ref,        # (B,) int32 — total valid tokens incl. the chunk
     q_ref,              # (1, 1, G, Sq, hd)
-    k_ref,              # (1, kvb, 1, hd)
-    v_ref,              # (1, kvb, 1, hd)
+    k_ref,              # (kvb, hd)
+    v_ref,              # (kvb, hd)
     o_ref,              # (1, 1, G, Sq, hd)
     m_ref,              # (G*Sq, 1) f32
     l_ref,              # (G*Sq, 1) f32
@@ -49,8 +52,8 @@ def _kernel(
     g = q_ref.shape[2]
     hd = q_ref.shape[-1]
     q = q_ref[0, 0].astype(jnp.float32).reshape(g * sq, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)                 # (kvb, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[...].astype(jnp.float32)                 # (kvb, hd)
+    v = v_ref[...].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -82,8 +85,8 @@ def _kernel(
 def _packed_kernel(
     ctx_lens_ref,       # (S,) int32 — cached tokens BEFORE each chunk
     q_ref,              # (1, 1, G, Sq, hd)
-    k_ref,              # (1, kvb, 1, hd)
-    v_ref,              # (1, kvb, 1, hd)
+    k_ref,              # (kvb, hd)
+    v_ref,              # (kvb, hd)
     o_ref,              # (1, 1, G, Sq, hd)
     m_ref,              # (G*Sq, 1) f32
     l_ref,              # (G*Sq, 1) f32
@@ -113,8 +116,8 @@ def _packed_kernel(
         g = q_ref.shape[2]
         hd = q_ref.shape[-1]
         q = q_ref[0, 0].astype(jnp.float32).reshape(g * sq, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)             # (kvb, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)             # (kvb, hd)
+        v = v_ref[...].astype(jnp.float32)
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -152,7 +155,7 @@ def packed_prefill_attention(q, k_cache, v_cache, ctx_lens,
 
     q: (S, Sq, H, hd) — per-segment chunk queries, right-padded to a common
     ``Sq`` (padded rows are masked out by the consumer); k/v_cache:
-    (S, Smax, Hkv, hd) staged per-segment caches with each chunk's K/V
+    (S, Hkv, Smax, hd) head-major staged per-segment caches with each chunk's K/V
     already written at [ctx, ctx+chunk); ctx_lens: (S,) cached tokens
     BEFORE each chunk.  Query row r of segment s sits at absolute position
     ``ctx_lens[s] + r`` — identical masking to the per-request kernel with
@@ -160,13 +163,13 @@ def packed_prefill_attention(q, k_cache, v_cache, ctx_lens,
     equal to S separate ``chunked_prefill_attention`` calls.
     Returns (S, Sq, H, hd)."""
     s_, sq, h, hd = q.shape
-    smax, hkv = k_cache.shape[1], k_cache.shape[2]
+    hkv, smax = k_cache.shape[1], k_cache.shape[2]
     g = h // hkv
     n_steps = -(-smax // kv_block)
     if smax % kv_block:
         padlen = n_steps * kv_block - smax
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, padlen), (0, 0), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, padlen), (0, 0), (0, 0)))
+        k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, padlen), (0, 0)))
+        v_cache = jnp.pad(v_cache, ((0, 0), (0, 0), (0, padlen), (0, 0)))
     q5 = q.reshape(s_, sq, hkv, g, hd).transpose(0, 2, 3, 1, 4)
 
     grid = (s_, hkv, n_steps)
@@ -175,7 +178,7 @@ def packed_prefill_attention(q, k_cache, v_cache, ctx_lens,
         return (bi, hi, 0, 0, 0)
 
     def kv_map(bi, hi, ii, ln):
-        return (bi, ii, hi, 0)
+        return (bi, hi, ii, 0)
 
     out = pl.pallas_call(
         functools.partial(_packed_kernel, kv_block=kv_block,
@@ -185,8 +188,8 @@ def packed_prefill_attention(q, k_cache, v_cache, ctx_lens,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, g, sq, hd), q_map),
-                pl.BlockSpec((1, kv_block, 1, hd), kv_map),
-                pl.BlockSpec((1, kv_block, 1, hd), kv_map),
+                pl.BlockSpec((None, None, kv_block, hd), kv_map),
+                pl.BlockSpec((None, None, kv_block, hd), kv_map),
             ],
             out_specs=pl.BlockSpec((1, 1, g, sq, hd), q_map),
             scratch_shapes=[
@@ -204,17 +207,17 @@ def packed_prefill_attention(q, k_cache, v_cache, ctx_lens,
 def chunked_prefill_attention(q, k_cache, v_cache, cache_lens,
                               *, kv_block: int = 512,
                               interpret: bool = False):
-    """q: (B, Sq, H, hd); k/v_cache: (B, Smax, Hkv, hd) with the chunk's K/V
+    """q: (B, Sq, H, hd); k/v_cache: (B, Hkv, Smax, hd) with the chunk's K/V
     already written at [len-Sq, len); cache_lens: (B,) valid lengths
     INCLUDING the chunk.  Returns (B, Sq, H, hd)."""
     b, sq, h, hd = q.shape
-    smax, hkv = k_cache.shape[1], k_cache.shape[2]
+    hkv, smax = k_cache.shape[1], k_cache.shape[2]
     g = h // hkv
     n_steps = -(-smax // kv_block)
     if smax % kv_block:
         padlen = n_steps * kv_block - smax
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, padlen), (0, 0), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, padlen), (0, 0), (0, 0)))
+        k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, padlen), (0, 0)))
+        v_cache = jnp.pad(v_cache, ((0, 0), (0, 0), (0, padlen), (0, 0)))
     # (B, Sq, H, hd) -> (B, Hkv, G, Sq, hd)
     q5 = q.reshape(b, sq, hkv, g, hd).transpose(0, 2, 3, 1, 4)
 
@@ -224,7 +227,7 @@ def chunked_prefill_attention(q, k_cache, v_cache, cache_lens,
         return (bi, hi, 0, 0, 0)
 
     def kv_map(bi, hi, ii, ln):
-        return (bi, ii, hi, 0)
+        return (bi, hi, ii, 0)
 
     out = pl.pallas_call(
         functools.partial(_kernel, kv_block=kv_block, n_steps=n_steps,
@@ -234,8 +237,8 @@ def chunked_prefill_attention(q, k_cache, v_cache, cache_lens,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, g, sq, hd), q_map),
-                pl.BlockSpec((1, kv_block, 1, hd), kv_map),
-                pl.BlockSpec((1, kv_block, 1, hd), kv_map),
+                pl.BlockSpec((None, None, kv_block, hd), kv_map),
+                pl.BlockSpec((None, None, kv_block, hd), kv_map),
             ],
             out_specs=pl.BlockSpec((1, 1, g, sq, hd), q_map),
             scratch_shapes=[

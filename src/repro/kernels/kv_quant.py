@@ -9,7 +9,7 @@ Quantization is symmetric per (block, layer, k|v) *plane*:
     x'    = q * scale
 
 The per-plane granularity matches the offload wire unit — one KV block is
-``(L, 2, bs, Hkv, hd)`` and each of its ``L*2`` planes gets its own fp32
+``(L, 2, Hkv, bs, hd)`` and each of its ``L*2`` planes gets its own fp32
 scale — so a single outlier key only widens the step of its own layer's
 K (or V) plane, not the whole block.
 
@@ -18,10 +18,13 @@ satisfies ``|x - x'| <= scale/2`` (asserted by tests/test_kernels.py).
 All ops are elementwise or exact reductions (abs/max), so the kernels are
 bitwise-identical to the ``ref.py`` oracles in interpret mode.
 
-Grid: one program per plane row — the input is viewed as ``(R, E)`` with
-``R = n*L*2`` rows of ``E = bs*Hkv*hd`` elements; each program reduces one
-row to its scale and writes the quantized row (quantize) or applies the
-row's scale (dequantize).
+Grid: one program per plane row — the input is viewed as ``(R, 1, E)``
+with ``R = n*L*2`` rows of ``E = Hkv*bs*hd`` elements and the scales as
+``(R, 1, 1)``; each program reduces one row to its scale and writes the
+quantized row (quantize) or applies the row's scale (dequantize).  The
+unit middle axis makes each ``(1, 1, E)`` block span the array's full
+trailing two dims, which the TPU compiler requires of a block whose
+row count is not a multiple of 8.
 """
 from __future__ import annotations
 
@@ -43,54 +46,52 @@ def _quant_kernel(x_ref, vals_ref, scales_ref):
 
 
 def _dequant_kernel(vals_ref, scales_ref, out_ref):
-    scale = scales_ref[0, 0]
+    scale = scales_ref[0, 0, 0]
     out_ref[...] = vals_ref[...].astype(jnp.float32) * scale
 
 
 def _row_view(blocks):
-    n, lyr, two, bs, hkv, hd = blocks.shape
-    return blocks.reshape(n * lyr * two, bs * hkv * hd)
+    n, lyr, two = blocks.shape[:3]
+    return blocks.reshape(n * lyr * two, 1, -1)
+
+
+def _row_map(i):
+    return (i, 0, 0)
 
 
 def kv_block_quantize(blocks, *, interpret: bool = False):
-    """blocks: (n, L, 2, bs, Hkv, hd) float -> (int8 vals same shape,
+    """blocks: (n, L, 2, Hkv, bs, hd) float -> (int8 vals same shape,
     fp32 scales (n, L, 2))."""
-    n, lyr, two, bs, hkv, hd = blocks.shape
+    n, lyr, two = blocks.shape[:3]
     x = _row_view(blocks)
-    r, e = x.shape
-
-    def row_map(i):
-        return (i, 0)
+    r, _, e = x.shape
 
     vals, scales = pl.pallas_call(
         _quant_kernel,
         grid=(r,),
-        in_specs=[pl.BlockSpec((1, e), row_map)],
-        out_specs=[pl.BlockSpec((1, e), row_map),
-                   pl.BlockSpec((1, 1), row_map)],
-        out_shape=[jax.ShapeDtypeStruct((r, e), jnp.int8),
-                   jax.ShapeDtypeStruct((r, 1), jnp.float32)],
+        in_specs=[pl.BlockSpec((1, 1, e), _row_map)],
+        out_specs=[pl.BlockSpec((1, 1, e), _row_map),
+                   pl.BlockSpec((1, 1, 1), _row_map)],
+        out_shape=[jax.ShapeDtypeStruct((r, 1, e), jnp.int8),
+                   jax.ShapeDtypeStruct((r, 1, 1), jnp.float32)],
         interpret=interpret,
     )(x)
     return (vals.reshape(blocks.shape), scales.reshape(n, lyr, two))
 
 
 def kv_block_dequantize(vals, scales, *, interpret: bool = False):
-    """vals: (n, L, 2, bs, Hkv, hd) int8, scales: (n, L, 2) fp32 ->
+    """vals: (n, L, 2, Hkv, bs, hd) int8, scales: (n, L, 2) fp32 ->
     fp32 blocks of vals' shape."""
     q = _row_view(vals)
-    r, e = q.shape
-
-    def row_map(i):
-        return (i, 0)
+    r, _, e = q.shape
 
     out = pl.pallas_call(
         _dequant_kernel,
         grid=(r,),
-        in_specs=[pl.BlockSpec((1, e), row_map),
-                  pl.BlockSpec((1, 1), row_map)],
-        out_specs=pl.BlockSpec((1, e), row_map),
-        out_shape=jax.ShapeDtypeStruct((r, e), jnp.float32),
+        in_specs=[pl.BlockSpec((1, 1, e), _row_map),
+                  pl.BlockSpec((1, 1, 1), _row_map)],
+        out_specs=pl.BlockSpec((1, 1, e), _row_map),
+        out_shape=jax.ShapeDtypeStruct((r, 1, e), jnp.float32),
         interpret=interpret,
-    )(q, scales.reshape(r, 1))
+    )(q, scales.reshape(r, 1, 1))
     return out.reshape(vals.shape)

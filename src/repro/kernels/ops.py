@@ -1,8 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On the TPU target the kernels compile natively; on this CPU container they
-run in ``interpret=True`` mode (the kernel body executes as traced jnp ops)
-which is how the tests validate them against the ref.py oracles.
+On a TPU backend every kernel compiles with Mosaic (never interpreted):
+tests/test_tpu_compile.py compiles each one for a described v5e at
+Qwen1.5-0.5B widths.  On any other backend they run in ``interpret=True``
+mode (the kernel body executes as traced jnp ops), which is how the CPU
+tests validate them against the ref.py oracles.
 """
 from __future__ import annotations
 

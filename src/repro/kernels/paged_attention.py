@@ -8,6 +8,10 @@ requests directly against the paged pool:
     sequentially on-core, maintaining an online softmax in VMEM scratch
     (flash-decode), so nothing larger than one (page, head_dim) tile plus
     the (G, head_dim) accumulator ever sits in VMEM;
+  * pages are HEAD-MAJOR, ``(P, Hkv, page, hd)``: one (page, hd) tile is
+    the trailing two dims of the array, which is what lets the TPU
+    compiler tile a one-head block (a token-major ``(P, page, Hkv, hd)``
+    page puts Hkv second-to-last and a one-head block can never tile);
   * page indices are SCALAR-PREFETCHED (PrefetchScalarGridSpec): the block
     table drives the K/V BlockSpec index_map, so each grid step DMAs
     exactly the page it needs — the TPU analogue of vLLM's gather, with no
@@ -15,8 +19,8 @@ requests directly against the paged pool:
   * GQA: the G = H/Hkv query heads of a kv group are processed together as
     the row dimension of the (G, page) score tile.
 
-TPU mapping notes (DESIGN.md §2): page_size should be a multiple of 128
-(lane dim) and head_dim 128 for MXU alignment; G < 8 underfills the MXU
+TPU mapping notes: a page size that is a multiple of 8 (sublanes) and a
+head_dim of 128 (lanes) fill whole tiles; G < 8 underfills the MXU
 sublane dim — acceptable for decode, which is DMA-bound anyway.
 """
 from __future__ import annotations
@@ -37,11 +41,11 @@ def _kernel(
     block_tables_ref,    # (B, maxp) int32
     lengths_ref,         # (B,) int32
     # --- blocked operands ---
-    q_ref,               # (1, 1, G, hd)
-    k_ref,               # (1, page, 1, hd)
-    v_ref,               # (1, page, 1, hd)
+    q_ref,               # (G, hd)
+    k_ref,               # (page, hd)
+    v_ref,               # (page, hd)
     # --- blocked output ---
-    o_ref,               # (1, 1, G, hd)
+    o_ref,               # (G, hd)
     # --- scratch ---
     m_ref,               # (G, 1) f32
     l_ref,               # (G, 1) f32
@@ -57,9 +61,9 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                    # (G, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)                 # (page, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    q = q_ref[...].astype(jnp.float32)                     # (G, hd)
+    k = k_ref[...].astype(jnp.float32)                     # (page, hd)
+    v = v_ref[...].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -81,17 +85,17 @@ def _kernel(
 
     @pl.when(i == max_pages - 1)
     def _out():
-        o_ref[0, 0] = (acc_ref[...] /
+        o_ref[...] = (acc_ref[...] /
                        jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
                            *, interpret: bool = False):
-    """q: (B, H, hd); k/v_pages: (P, page, Hkv, hd);
+    """q: (B, H, hd); k/v_pages: (P, Hkv, page, hd) head-major pages;
     block_tables: (B, maxp) int32 (pad with 0); lengths: (B,) int32.
     Returns (B, H, hd)."""
     b, h, hd = q.shape
-    n_pages, page, hkv, _ = k_pages.shape
+    n_pages, hkv, page, _ = k_pages.shape
     g = h // hkv
     maxp = block_tables.shape[1]
     q4 = q.reshape(b, hkv, g, hd)
@@ -102,7 +106,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
         return (bi, hi, 0, 0)
 
     def kv_map(bi, hi, ii, bt, ln):
-        return (bt[bi, ii], 0, hi, 0)
+        return (bt[bi, ii], hi, 0, 0)
 
     def o_map(bi, hi, ii, bt, ln):
         return (bi, hi, 0, 0)
@@ -113,11 +117,11 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, g, hd), q_map),
-                pl.BlockSpec((1, page, 1, hd), kv_map),
-                pl.BlockSpec((1, page, 1, hd), kv_map),
+                pl.BlockSpec((None, None, g, hd), q_map),
+                pl.BlockSpec((None, None, page, hd), kv_map),
+                pl.BlockSpec((None, None, page, hd), kv_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, g, hd), o_map),
+            out_specs=pl.BlockSpec((None, None, g, hd), o_map),
             scratch_shapes=[
                 pltpu.VMEM((g, 1), jnp.float32),
                 pltpu.VMEM((g, 1), jnp.float32),

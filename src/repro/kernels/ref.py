@@ -13,16 +13,19 @@ import jax.numpy as jnp
 
 
 def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, lengths):
-    """q: (B, H, hd); pages: (P, page, Hkv, hd); block_tables: (B, maxp);
-    lengths: (B,).  Returns (B, H, hd)."""
+    """q: (B, H, hd); pages: (P, Hkv, page, hd) head-major;
+    block_tables: (B, maxp); lengths: (B,).  Returns (B, H, hd)."""
     b, h, hd = q.shape
-    page = k_pages.shape[1]
-    hkv = k_pages.shape[2]
+    hkv, page = k_pages.shape[1], k_pages.shape[2]
     g = h // hkv
     maxp = block_tables.shape[1]
-    # gather pages -> (B, maxp*page, Hkv, hd)
-    k = k_pages[block_tables].reshape(b, maxp * page, hkv, hd)
-    v = v_pages[block_tables].reshape(b, maxp * page, hkv, hd)
+
+    def linear(pages):
+        # (B, maxp, Hkv, page, hd) -> token-major (B, maxp*page, Hkv, hd)
+        return pages[block_tables].transpose(0, 1, 3, 2, 4).reshape(
+            b, maxp * page, hkv, hd)
+
+    k, v = linear(k_pages), linear(v_pages)
     q4 = q.reshape(b, hkv, g, hd).astype(jnp.float32)
     s = jnp.einsum("bkgd,bskd->bkgs", q4, k.astype(jnp.float32))
     s = s / math.sqrt(hd)
@@ -38,7 +41,7 @@ def packed_verify_attention_ref(q, k_pages, v_pages, block_tables, lengths,
                                 row_seg):
     """Packed speculative-verify oracle: rows sharing a request share a
     block-table row via ``row_seg``.  q: (R, H, hd); pages:
-    (P, page, Hkv, hd); block_tables: (S, maxp); lengths/row_seg: (R,).
+    (P, Hkv, page, hd); block_tables: (S, maxp); lengths/row_seg: (R,).
     Gathers each row's table and then applies the exact
     ``paged_decode_attention_ref`` math.  Returns (R, H, hd)."""
     return paged_decode_attention_ref(q, k_pages, v_pages,
@@ -49,34 +52,34 @@ def chunked_prefill_attention_ref(q, k_cache, v_cache, cache_lens):
     """Chunked-prefill attention: the new chunk's K/V are ALREADY written
     into the cache at [cache_lens - Sq, cache_lens).
 
-    q: (B, Sq, H, hd) — queries of the chunk; k/v_cache: (B, Smax, Hkv, hd);
-    cache_lens: (B,) total valid tokens INCLUDING the chunk.
+    q: (B, Sq, H, hd) — queries of the chunk; k/v_cache: (B, Hkv, Smax, hd)
+    head-major; cache_lens: (B,) total valid tokens INCLUDING the chunk.
     Query row j sits at absolute position cache_lens - Sq + j and attends
     causally.  Returns (B, Sq, H, hd).
     """
     b, sq, h, hd = q.shape
-    smax, hkv = k_cache.shape[1], k_cache.shape[2]
+    hkv, smax = k_cache.shape[1], k_cache.shape[2]
     g = h // hkv
     q5 = q.reshape(b, sq, hkv, g, hd).astype(jnp.float32)
-    s = jnp.einsum("bqkgd,bskd->bkgqs", q5, k_cache.astype(jnp.float32))
+    s = jnp.einsum("bqkgd,bksd->bkgqs", q5, k_cache.astype(jnp.float32))
     s = s / math.sqrt(hd)
     q_pos = (cache_lens[:, None] - sq + jnp.arange(sq)[None, :])   # (B, Sq)
     k_pos = jnp.arange(smax)[None, :]
     mask = k_pos[:, None, :] <= q_pos[..., None]                   # (B,Sq,Smax)
     s = jnp.where(mask[:, None, None, :, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgqs,bskd->bqkgd", p, v_cache.astype(jnp.float32))
+    o = jnp.einsum("bkgqs,bksd->bqkgd", p, v_cache.astype(jnp.float32))
     return o.reshape(b, sq, h, hd).astype(q.dtype)
 
 
 def block_gather_ref(pool, indices):
-    """pool: (P, page, ...); indices: (n,) -> (n, page, ...)."""
+    """pool: (P, *page_shape); indices: (n,) -> (n, *page_shape)."""
     return pool[indices]
 
 
 def kv_block_quantize_ref(blocks):
     """Symmetric int8 per-(block, layer, k|v)-plane quantization.
-    blocks: (n, L, 2, bs, Hkv, hd) -> (int8 vals same shape, fp32 scales
+    blocks: (n, L, 2, Hkv, bs, hd) -> (int8 vals same shape, fp32 scales
     (n, L, 2)).  Expression shapes deliberately mirror kv_quant.py so the
     kernel is BITWISE equal in interpret mode."""
     n, lyr, two = blocks.shape[:3]
@@ -88,7 +91,7 @@ def kv_block_quantize_ref(blocks):
 
 
 def kv_block_dequantize_ref(vals, scales):
-    """vals: (n, L, 2, bs, Hkv, hd) int8, scales: (n, L, 2) -> fp32
+    """vals: (n, L, 2, Hkv, bs, hd) int8, scales: (n, L, 2) -> fp32
     blocks.  Roundtrip error vs the original is bounded by scale/2 per
     element (see kv_quant.py)."""
     n, lyr, two = vals.shape[:3]

@@ -39,11 +39,11 @@ def _kernel(
     block_tables_ref,    # (S, maxp) int32
     lengths_ref,         # (R,) int32
     # --- blocked operands ---
-    q_ref,               # (1, 1, G, hd)
-    k_ref,               # (1, page, 1, hd)
-    v_ref,               # (1, page, 1, hd)
+    q_ref,               # (G, hd)
+    k_ref,               # (page, hd)
+    v_ref,               # (page, hd)
     # --- blocked output ---
-    o_ref,               # (1, 1, G, hd)
+    o_ref,               # (G, hd)
     # --- scratch ---
     m_ref,               # (G, 1) f32
     l_ref,               # (G, 1) f32
@@ -59,9 +59,9 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                    # (G, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)                 # (page, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    q = q_ref[...].astype(jnp.float32)                     # (G, hd)
+    k = k_ref[...].astype(jnp.float32)                     # (page, hd)
+    v = v_ref[...].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -83,19 +83,19 @@ def _kernel(
 
     @pl.when(i == max_pages - 1)
     def _out():
-        o_ref[0, 0] = (acc_ref[...] /
+        o_ref[...] = (acc_ref[...] /
                        jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def packed_verify_attention(q, k_pages, v_pages, block_tables, lengths,
                             row_seg, *, interpret: bool = False):
     """q: (R, H, hd) — one row per (request, draft position);
-    k/v_pages: (P, page, Hkv, hd); block_tables: (S, maxp) int32 (pad
+    k/v_pages: (P, Hkv, page, hd) head-major pages; block_tables: (S, maxp) int32 (pad
     with 0); lengths: (R,) int32 — per ROW (l_kv + j + 1);
     row_seg: (R,) int32 — row -> block-table row in [0, S).
     Returns (R, H, hd)."""
     b, h, hd = q.shape
-    n_pages, page, hkv, _ = k_pages.shape
+    n_pages, hkv, page, _ = k_pages.shape
     g = h // hkv
     maxp = block_tables.shape[1]
     q4 = q.reshape(b, hkv, g, hd)
@@ -106,7 +106,7 @@ def packed_verify_attention(q, k_pages, v_pages, block_tables, lengths,
         return (bi, hi, 0, 0)
 
     def kv_map(bi, hi, ii, seg, bt, ln):
-        return (bt[seg[bi], ii], 0, hi, 0)
+        return (bt[seg[bi], ii], hi, 0, 0)
 
     def o_map(bi, hi, ii, seg, bt, ln):
         return (bi, hi, 0, 0)
@@ -117,11 +117,11 @@ def packed_verify_attention(q, k_pages, v_pages, block_tables, lengths,
             num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, g, hd), q_map),
-                pl.BlockSpec((1, page, 1, hd), kv_map),
-                pl.BlockSpec((1, page, 1, hd), kv_map),
+                pl.BlockSpec((None, None, g, hd), q_map),
+                pl.BlockSpec((None, None, page, hd), kv_map),
+                pl.BlockSpec((None, None, page, hd), kv_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, g, hd), o_map),
+            out_specs=pl.BlockSpec((None, None, g, hd), o_map),
             scratch_shapes=[
                 pltpu.VMEM((g, 1), jnp.float32),
                 pltpu.VMEM((g, 1), jnp.float32),

@@ -5,16 +5,16 @@ from .kv_pool import PagedKVPool
 from .prefix_cache import RadixPrefixCache
 from .spec import DraftRunner
 from .transfer import TransferDone, TransferWorker
-from .engine import (Engine, EngineDriver, EngineStats, HandoffAdopted,
-                     HandoffDropped, HandoffEvent, HandoffPayload,
-                     StepEvent, TokenEvent)
+from .engine import (DriverFailed, Engine, EngineDriver, EngineStats,
+                     HandoffAdopted, HandoffDropped, HandoffEvent,
+                     HandoffPayload, StepEvent, TokenEvent)
 from .dispatch import RouterBook
 from .service import ServiceController, ServiceConfig
 from .frontend import (AdmissionError, FrontendConfig, RequestStream,
                        ServiceFrontend)
 
 __all__ = ["PagedKVPool", "RadixPrefixCache", "DraftRunner", "TransferDone",
-           "TransferWorker", "Engine", "EngineDriver",
+           "TransferWorker", "DriverFailed", "Engine", "EngineDriver",
            "EngineStats", "HandoffAdopted", "HandoffDropped",
            "HandoffEvent", "HandoffPayload", "StepEvent", "TokenEvent",
            "RouterBook",

@@ -34,6 +34,7 @@ Two driving modes:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import queue
 import threading
@@ -61,6 +62,17 @@ from .spec import DraftRunner
 from .transfer import TransferWorker
 
 logger = logging.getLogger(__name__)
+
+
+def _on_device(method):
+    """Run an Engine entry point with the replica's device as JAX's default
+    (thread-local), so every array the engine builds from host data lands
+    on its own chip.  ``device=None`` keeps the process default."""
+    @functools.wraps(method)
+    def wrapped(self, *args, **kwargs):
+        with jax.default_device(self.device):
+            return method(self, *args, **kwargs)
+    return wrapped
 
 
 @dataclass(frozen=True)
@@ -122,6 +134,15 @@ class HandoffDropped:
     request over to a re-prefill."""
     iid: int                 # target (decode) instance that refused
     payload: HandoffPayload
+
+
+@dataclass(frozen=True)
+class DriverFailed:
+    """A driver thread died: ``Engine.step`` (or an import) raised.  The
+    replica serves nothing more; the frontend fails its open streams with
+    ``error`` instead of leaving their consumers waiting forever."""
+    iid: int
+    error: BaseException
 
 
 @dataclass(frozen=True)
@@ -200,13 +221,18 @@ class Engine:
                  role: str = "coloc",
                  handoff_quantize: bool = False,
                  spec_draft: Optional[tuple] = None,
-                 spec_draft_blocks: Optional[int] = None):
+                 spec_draft_blocks: Optional[int] = None,
+                 device: Optional[jax.Device] = None):
         if role not in ("coloc", "prefill", "decode"):
             raise ValueError(f"unknown engine role: {role!r}")
         if eng_cfg.spec_k > 0 and spec_draft is None:
             raise ValueError("spec_k > 0 requires spec_draft=(cfg, params)")
         self.cfg = cfg
-        self.params = params
+        # ``device`` pins this replica (params, KV pool, draft, staging
+        # buffers) to one chip; None keeps the default device
+        self.device = device
+        self.params = (params if device is None
+                       else jax.device_put(params, device))
         # a role-parameterized replica runs the same pipeline; the role
         # only (a) flips the policy's pd_mode (prefill replicas price
         # admission with the prefill-phase phi), (b) arms the handoff
@@ -226,7 +252,7 @@ class Engine:
         # unbounded host mirror with bitwise-identical token streams
         self.pool = PagedKVPool(cfg, num_blocks, block_size,
                                 host_tier_bytes=host_tier_bytes,
-                                cold_quantize=cold_quantize)
+                                cold_quantize=cold_quantize, device=device)
         self.bm = BlockManager(num_blocks - 1, block_size, t_block,
                                **(bm_kwargs or {}))
         # radix prefix cache: shares prompt KV across requests (refcounted
@@ -256,9 +282,9 @@ class Engine:
             dcfg, dparams = spec_draft
             self.draft = DraftRunner(
                 dcfg, dparams, num_blocks=spec_draft_blocks or num_blocks,
-                block_size=block_size, max_ctx=max_ctx)
+                block_size=block_size, max_ctx=max_ctx, device=device)
         self.worker: Optional[TransferWorker] = (
-            TransferWorker() if overlap_transfers else None)
+            TransferWorker(device=device) if overlap_transfers else None)
         if self.cache is not None:
             # spill restores prefer buffers the worker pre-staged
             self.cache.worker = self.worker
@@ -298,6 +324,7 @@ class Engine:
                                          None]] = None
 
     # ------------------------------------------------------------------
+    @_on_device
     def add_request(self, req: Request, prompt_tokens: np.ndarray,
                     prior_outputs: Optional[list[int]] = None) -> None:
         """``prior_outputs``: tokens already streamed to the client before a
@@ -609,6 +636,7 @@ class Engine:
                 return list(p.outputs)
         return None
 
+    @_on_device
     def import_handoff(self, payload: HandoffPayload) -> bool:
         """Decode side: adopt a prefill peer's KV payload and continue the
         decode leg exactly where the source stopped.  All blocks land in
@@ -665,6 +693,7 @@ class Engine:
         self._wall_epoch = epoch
         self.now = max(self.now, time.monotonic() - epoch)
 
+    @_on_device
     def step(self) -> Optional[dict]:
         if not self.alive:
             return None
@@ -963,53 +992,15 @@ class Engine:
         into one flat token stream and executed in a single bucketed jit
         call — and each segment stages only the blocks it needs, instead
         of the engine-wide ``max_ctx`` span per chunk."""
-        bs = self.pool.block_size
         for e in entries:
             self._prepare_prefill(e)
-        n_seg = len(entries)
-        sq = model_exec.chunk_bucket(max(e.n_tokens for e in entries))
-        smax = model_exec.chunk_bucket(
-            max(e.l_kv + e.n_tokens for e in entries))
-        smax = -(-smax // bs) * bs
-        maxp = smax // bs
-        total = sum(e.n_tokens for e in entries)
-        t_b = model_exec.flat_bucket(total)
-        s_b = model_exec.seg_bucket(n_seg)
-
-        tokens = np.zeros((1, t_b), np.int32)
-        positions = np.zeros((1, t_b), np.int32)
-        q_rows = np.full((t_b,), s_b, np.int32)   # padding -> extra row
-        q_cols = np.zeros((t_b,), np.int32)
-        sblocks = np.zeros((t_b,), np.int32)      # padding -> null block 0
-        sslots = np.zeros((t_b,), np.int32)
-        tables = np.zeros((s_b, maxp), np.int32)
-        ctx_lens = np.zeros((s_b,), np.int32)
-        last_idx = np.zeros((s_b,), np.int32)
-        off = 0
-        for i, e in enumerate(entries):
-            r, ctx, n = e.req, e.l_kv, e.n_tokens
-            seq = self._seq_view(r)
-            tokens[0, off:off + n] = seq[ctx:ctx + n]
-            pos = np.arange(ctx, ctx + n, dtype=np.int32)
-            positions[0, off:off + n] = pos
-            q_rows[off:off + n] = i
-            q_cols[off:off + n] = np.arange(n, dtype=np.int32)
-            t = np.asarray(self.pool.tables[r.rid], np.int32)
-            sblocks[off:off + n] = t[pos // bs]
-            sslots[off:off + n] = pos % bs
-            k = min(len(t), maxp)
-            tables[i, :k] = t[:k]
-            ctx_lens[i] = ctx
-            last_idx[i] = off + n - 1
-            off += n
-
+        chunks = [(self._seq_view(e.req)[e.l_kv:e.l_kv + e.n_tokens],
+                   e.l_kv, self.pool.tables[e.req.rid]) for e in entries]
+        arrays, smax, sq = model_exec.pack_prefill(chunks,
+                                                   self.pool.block_size)
         logits, self.pool.kv = model_exec.prefill_packed(
             self.cfg, self.params, self.pool.kv,
-            jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(q_rows), jnp.asarray(q_cols),
-            jnp.asarray(sblocks), jnp.asarray(sslots),
-            jnp.asarray(tables), jnp.asarray(ctx_lens),
-            jnp.asarray(last_idx), smax, sq)
+            *(jnp.asarray(a) for a in arrays), smax, sq)
         self.stats.packed_prefill_calls += 1
         self.stats.host_syncs += 1
         nxt = np.asarray(jnp.argmax(logits, -1))
@@ -1084,6 +1075,7 @@ class Engine:
                     exc_info=True)
         self._profile = self._profile[-200:]
 
+    @_on_device
     def flush_transfers(self, timeout: float = 30.0) -> bool:
         """Wait for the background lanes to drain, then fold the completed
         transfers into the accounting (tests / benchmarks)."""
@@ -1157,6 +1149,8 @@ class EngineDriver:
         self._stop = threading.Event()
         self._idle = threading.Event()
         self._idle.set()
+        # the exception that killed the driver thread, if one did
+        self.error: Optional[BaseException] = None
         self._thread = threading.Thread(
             target=self._run, name=name or f"engine-driver-{iid}",
             daemon=True)
@@ -1223,6 +1217,17 @@ class EngineDriver:
                              time.monotonic(), first, last))
 
     def _run(self) -> None:
+        try:
+            self._loop()
+        except Exception as e:
+            # the thread's only way out besides stop(): record the error
+            # and hand it to the sink so the failure reaches the caller
+            logger.exception("engine driver %d died", self.iid)
+            self.error = e
+            self._idle.set()
+            self.sink(DriverFailed(self.iid, e))
+
+    def _loop(self) -> None:
         eng = self.engine
         while not self._stop.is_set():
             drained = False

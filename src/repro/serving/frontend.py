@@ -41,8 +41,9 @@ from ..core.estimator import BatchLatencyEstimator
 from ..core.gorouting import pick_decode_target
 from ..core.request import Request
 from .dispatch import RouterBook
-from .engine import (Engine, EngineDriver, HandoffAdopted, HandoffDropped,
-                     HandoffEvent, HandoffPayload, StepEvent, TokenEvent)
+from .engine import (DriverFailed, Engine, EngineDriver, HandoffAdopted,
+                     HandoffDropped, HandoffEvent, HandoffPayload, StepEvent,
+                     TokenEvent)
 
 
 class AdmissionError(RuntimeError):
@@ -180,6 +181,8 @@ class ServiceFrontend:
         self.finished: list[Request] = []
         self.completed_streams: list[RequestStream] = []
         self.rejected = 0
+        # iid -> exception that killed that replica's driver thread
+        self.driver_errors: dict[int, BaseException] = {}
         self._started = False
 
     # --- fleet management -----------------------------------------------
@@ -359,7 +362,33 @@ class ServiceFrontend:
                 self._on_handoff_adopted(ev.iid, ev.payload)
             elif isinstance(ev, HandoffDropped):
                 self._redispatch(ev.payload.req)
+            elif isinstance(ev, DriverFailed):
+                self._on_driver_failed(ev)
         return sink
+
+    def _on_driver_failed(self, ev: DriverFailed) -> None:
+        """A replica's driver thread raised: record the error, stop routing
+        to the replica, and fail every stream it held with that error (a
+        crash is a fault to surface, not load to move elsewhere)."""
+        with self._lock:
+            self.driver_errors[ev.iid] = ev.error
+            self.book.drop_instance(ev.iid)
+            rids = [rid for rid, iid in self._rid_iid.items()
+                    if iid == ev.iid]
+            streams = []
+            for rid in rids:
+                stream = self._streams.pop(rid, None)
+                req = self._reqs.get(rid)
+                self.book.forget(rid)
+                if req is not None:
+                    self._release_slot(req)
+                if stream is not None:
+                    streams.append(stream)
+        if self._loop is not None:
+            err = RuntimeError(f"engine replica {ev.iid} failed: "
+                               f"{ev.error!r}")
+            for stream in streams:
+                self._loop.call_soon_threadsafe(stream._close, err)
 
     # --- disagg two-leg lifecycle (driver threads) ------------------------
     def _on_handoff(self, src_iid: int, payload: HandoffPayload) -> None:

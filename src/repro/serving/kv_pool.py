@@ -1,8 +1,12 @@
 """Paged KV pool: the real device-side block store + tiered host mirror.
 
-Layout: one device array ``(L, 2, num_blocks, block_size, Hkv, hd)``
-(k=0 / v=1), addressed through per-request block tables.  Off-device
-residency is TIERED (``KVTierStore``): a capacity-bounded HOST tier of
+Layout: one device array ``(L, 2, num_blocks, Hkv, block_size, hd)``
+(k=0 / v=1), addressed through per-request block tables.  Pages are
+head-major so a one-head ``(block_size, hd)`` tile is the array's trailing
+two dims — the block shape the Pallas kernels need to tile on a TPU.  One
+block as the host tiers and transfer lanes see it is
+``(L, 2, Hkv, bs, hd)``.  Off-device residency is TIERED
+(``KVTierStore``): a capacity-bounded HOST tier of
 fp32 numpy blocks (the §4.3 asynchronous-offload target) and an
 unbounded int8-quantized COLD tier that host-tier evictions demote into
 (per-plane scales; see ``kernels/kv_quant.py`` for the wire format and
@@ -55,7 +59,7 @@ class KVTierStore:
         self.budget_bytes = budget_bytes
         self.cold_quantize = cold_quantize
         self.hot: dict[int, dict[int, np.ndarray]] = {}
-        # bi -> (int8 vals (L,2,bs,Hkv,hd), fp32 scales (L,2)) | fp32 array
+        # bi -> (int8 vals (L,2,Hkv,bs,hd), fp32 scales (L,2)) | fp32 array
         self.cold: dict[int, dict[int, object]] = {}
         self._touch: dict[int, int] = {}
         self._clock = 0
@@ -288,13 +292,16 @@ class _HostView:
 class PagedKVPool:
     def __init__(self, cfg: ArchConfig, num_blocks: int, block_size: int,
                  dtype=jnp.float32, host_tier_bytes: Optional[int] = None,
-                 cold_quantize: bool = True):
+                 cold_quantize: bool = True,
+                 device: Optional[jax.Device] = None):
         self.cfg = cfg
         self.num_blocks = num_blocks
         self.block_size = block_size
+        # ``device`` commits the pool to one chip (a pinned replica);
+        # None leaves it on the default device
         self.kv = jnp.zeros(
-            (cfg.n_layers, 2, num_blocks, block_size, cfg.n_kv_heads,
-             cfg.hd), dtype)
+            (cfg.n_layers, 2, num_blocks, cfg.n_kv_heads, block_size,
+             cfg.hd), dtype, device=device)
         self.free: list[int] = list(range(num_blocks - 1, 0, -1))
         # block 0 is reserved as the null page block tables pad with
         self.refcount: list[int] = [0] * num_blocks
@@ -302,7 +309,7 @@ class PagedKVPool:
         self.tables: dict[int, list[int]] = {}
         # tiered host mirror, keyed rid -> {logical block index -> contents}
         # (host_tier_bytes=None keeps the legacy unbounded fp32 behaviour)
-        block_bytes = int(cfg.n_layers * 2 * block_size * cfg.n_kv_heads
+        block_bytes = int(cfg.n_layers * 2 * cfg.n_kv_heads * block_size
                           * cfg.hd * np.dtype(dtype).itemsize)
         self.tier = KVTierStore(block_bytes, host_tier_bytes, cold_quantize)
         self._cache_rid = -1        # next radix-cache spill pseudo-rid
@@ -400,7 +407,7 @@ class PagedKVPool:
     # --- host offload / reload (§4.3 mechanism) ---------------------------
     def gather_blocks(self, rid: int, block_indices: list[int]):
         """Device-side snapshot of rid's logical blocks, shaped
-        (n, L, 2, bs, Hkv, hd).  Because jax arrays are functional the
+        (n, L, 2, Hkv, bs, hd).  Because jax arrays are functional the
         result is a race-free copy: later pool writes (or freeing the
         source blocks) cannot disturb it — this is what the background
         D2H lane consumes."""
@@ -458,7 +465,7 @@ class PagedKVPool:
         if not restorable:
             return 0
         dst = jnp.asarray([b for b, _ in restorable], jnp.int32)
-        # host blocks are (L, 2, bs, Hkv, hd); stack -> (n, L, 2, ...) and
+        # host blocks are (L, 2, Hkv, bs, hd); stack -> (n, L, 2, ...) and
         # move the block axis behind (L, 2) to match self.kv's layout
         data = jnp.moveaxis(
             jnp.asarray(np.stack([blk for _, blk in restorable])), 0, 2)
@@ -467,7 +474,7 @@ class PagedKVPool:
 
     def reload_from_device(self, rid: int, staged, n_blocks: int) -> int:
         """Staged variant of ``reload_blocks``: ``staged`` is a
-        (m, L, 2, bs, Hkv, hd) array the background H2D lane already
+        (m, L, 2, Hkv, bs, hd) array the background H2D lane already
         landed on device; scatter its first ``n_blocks`` into freshly
         allocated blocks in one pass.  Returns tokens restored."""
         n = min(n_blocks, staged.shape[0])
@@ -540,7 +547,7 @@ class PagedKVPool:
 
     def adopt_staged_group(self, host_rid: int, staged, n: int) -> list[int]:
         """Like ``restore_cache_group`` but the H2D copy already landed:
-        ``staged`` is the (m, L, 2, bs, Hkv, hd) device buffer the transfer
+        ``staged`` is the (m, L, 2, Hkv, bs, hd) device buffer the transfer
         worker pre-staged for this group."""
         if staged.shape[0] < n:
             return []

@@ -1,14 +1,18 @@
 """Jitted model execution against the paged KV pool.
 
-Two entry points, both shape-bucketed to bound recompilation:
+Entry points, all shape-bucketed to bound recompilation:
 
-* ``decode_batch``  — one token for B requests: per layer, project QKV,
-  scatter the new K/V into each request's current block slot, run
-  paged flash-decode attention (the Pallas kernel in interpret mode on
-  CPU, native on TPU — switchable to the jnp reference).
-* ``prefill_chunk`` — a chunk of ``c`` tokens for ONE request (chunked
-  prefill, Alg. 1): stages the request's context + writes new K/V, runs
-  chunked-prefill flash attention.
+* ``decode_step`` / ``decode_batch`` — one token for B requests: per
+  layer, project QKV, scatter the new K/V into each request's current
+  block slot, run paged flash-decode attention (the Pallas kernel,
+  compiled on TPU and interpreted on any other backend).
+* ``verify_step`` — the packed speculative-verify variant of decode.
+* ``prefill_packed`` / ``prefill_chunk`` — chunked prefill (Alg. 1) for
+  several requests in one flat stream, or for ONE request: write the new
+  K/V, stage each request's context, run chunked-prefill flash attention.
+
+The pool is ``(L, 2, P, Hkv, bs, hd)``: each page is head-major so the
+kernels' one-head ``(bs, hd)`` tiles are the array's trailing two dims.
 
 Only dense/GQA families are supported by the real engine demo
 (qwen1.5-0.5b smoke-scale is the example model); the simulator covers all
@@ -20,6 +24,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..kernels.ops import (chunked_prefill_attention,
                            packed_prefill_attention, packed_verify_attention,
@@ -35,13 +40,29 @@ def _mlp(cfg, lp, h):
     return swiglu(h, lp["mlp"]) if cfg.act == "swiglu" else gelu_mlp(h, lp["mlp"])
 
 
+def _write_kv(layer_kv, blocks, slots, k, v):
+    """Scatter per-token K/V ``(N, Hkv, hd)`` into a layer's head-major
+    pages ``(2, P, Hkv, bs, hd)`` at (block, slot) of each token."""
+    layer_kv = layer_kv.at[0, blocks, :, slots].set(k)
+    return layer_kv.at[1, blocks, :, slots].set(v)
+
+
+def _stage(pages, tables):
+    """Gather the pages of each table row into a contiguous head-major
+    stage: pages ``(P, Hkv, bs, hd)``, tables ``(S, maxp)`` ->
+    ``(S, Hkv, maxp*bs, hd)``."""
+    g = pages[tables]                                     # (S, maxp, Hkv, bs, hd)
+    s_, maxp, hkv, bs, hd = g.shape
+    return g.transpose(0, 2, 1, 3, 4).reshape(s_, hkv, maxp * bs, hd)
+
+
 def _decode_forward(cfg: ArchConfig, params, pool_kv, tokens, tables, lens):
     """Shared decode forward pass (traced by both ``decode_batch`` and the
     fused ``decode_step`` so the two jit variants run the identical graph).
     tokens: (B,) int32; tables: (B, maxp); lens: (B,) context BEFORE this
     step.  Returns (logits (B, V), new pool)."""
     b = tokens.shape[0]
-    bs = pool_kv.shape[3]
+    bs = pool_kv.shape[4]
     x = params["embed"][tokens][:, None, :].astype(pool_kv.dtype)
     positions = lens[:, None]
     block_of = tables[jnp.arange(b), lens // bs]          # (B,)
@@ -57,8 +78,7 @@ def _decode_forward(cfg: ArchConfig, params, pool_kv, tokens, tables, lens):
             k = apply_rope(k, positions, cfg.rope_fraction, cfg.rope_theta)
         # write the new K/V into each request's current block slot
         layer_kv = jax.lax.dynamic_index_in_dim(pool, li, 0, keepdims=False)
-        layer_kv = layer_kv.at[0, block_of, slot_of].set(k[:, 0])
-        layer_kv = layer_kv.at[1, block_of, slot_of].set(v[:, 0])
+        layer_kv = _write_kv(layer_kv, block_of, slot_of, k[:, 0], v[:, 0])
         pool = jax.lax.dynamic_update_index_in_dim(pool, layer_kv, li, 0)
         o = paged_decode_attention(q[:, 0], layer_kv[0], layer_kv[1],
                                    tables, lens + 1)
@@ -118,7 +138,7 @@ def _verify_forward(cfg: ArchConfig, params, pool_kv, tokens, tables, lens,
     request — so row j+1 attends to row j's same-launch write and the
     packed rows reproduce sequential greedy decode bitwise."""
     r = tokens.shape[0]
-    bs = pool_kv.shape[3]
+    bs = pool_kv.shape[4]
     x = params["embed"][tokens][:, None, :].astype(pool_kv.dtype)
     positions = lens[:, None]
     row_tables = tables[row_seg]                          # (R, maxp)
@@ -134,8 +154,7 @@ def _verify_forward(cfg: ArchConfig, params, pool_kv, tokens, tables, lens,
             q = apply_rope(q, positions, cfg.rope_fraction, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_fraction, cfg.rope_theta)
         layer_kv = jax.lax.dynamic_index_in_dim(pool, li, 0, keepdims=False)
-        layer_kv = layer_kv.at[0, block_of, slot_of].set(k[:, 0])
-        layer_kv = layer_kv.at[1, block_of, slot_of].set(v[:, 0])
+        layer_kv = _write_kv(layer_kv, block_of, slot_of, k[:, 0], v[:, 0])
         pool = jax.lax.dynamic_update_index_in_dim(pool, layer_kv, li, 0)
         o = packed_verify_attention(q[:, 0], layer_kv[0], layer_kv[1],
                                     tables, lens + 1, row_seg)
@@ -177,7 +196,7 @@ def prefill_chunk(cfg: ArchConfig, params, pool_kv, tokens, table, ctx_len,
     ``max_ctx``: static staging size (>= ctx+chunk).  Returns
     (last-position logits (1, V), new pool, valid_len)."""
     c = tokens.shape[1]
-    bs = pool_kv.shape[3]
+    bs = pool_kv.shape[4]
     x = params["embed"][tokens].astype(pool_kv.dtype)
     positions = ctx_len[:, None] + jnp.arange(c)[None, :]
     maxp_stage = max_ctx // bs
@@ -195,15 +214,12 @@ def prefill_chunk(cfg: ArchConfig, params, pool_kv, tokens, table, ctx_len,
         pos = ctx_len[0] + jnp.arange(c)
         blocks = table[0, pos // bs]
         slots = pos % bs
-        layer_kv = layer_kv.at[0, blocks, slots].set(k[0])
-        layer_kv = layer_kv.at[1, blocks, slots].set(v[0])
+        layer_kv = _write_kv(layer_kv, blocks, slots, k[0], v[0])
         pool = jax.lax.dynamic_update_index_in_dim(pool, layer_kv, li, 0)
         # stage the context (gather blocks) into a contiguous buffer
-        stage_blocks = table[0, :maxp_stage]
-        k_stage = layer_kv[0, stage_blocks].reshape(
-            1, max_ctx, cfg.n_kv_heads, cfg.hd)
-        v_stage = layer_kv[1, stage_blocks].reshape(
-            1, max_ctx, cfg.n_kv_heads, cfg.hd)
+        stage_blocks = table[:, :maxp_stage]
+        k_stage = _stage(layer_kv[0], stage_blocks)
+        v_stage = _stage(layer_kv[1], stage_blocks)
         o = chunked_prefill_attention(q, k_stage, v_stage, ctx_len + c)
         a_out = jnp.einsum("bsk,kd->bsd", o.reshape(1, c, -1),
                            lp["attn"]["wo"])
@@ -260,14 +276,12 @@ def prefill_packed(cfg: ArchConfig, params, pool_kv, tokens, positions,
             k = apply_rope(k, positions, cfg.rope_fraction, cfg.rope_theta)
         layer_kv = jax.lax.dynamic_index_in_dim(pool, li, 0, keepdims=False)
         # one flat scatter writes every segment's chunk K/V
-        layer_kv = layer_kv.at[0, scatter_blocks, scatter_slots].set(k[0])
-        layer_kv = layer_kv.at[1, scatter_blocks, scatter_slots].set(v[0])
+        layer_kv = _write_kv(layer_kv, scatter_blocks, scatter_slots,
+                             k[0], v[0])
         pool = jax.lax.dynamic_update_index_in_dim(pool, layer_kv, li, 0)
         # stage each segment's blocks (only the ones it needs)
-        k_stage = layer_kv[0, tables].reshape(
-            n_seg, smax, cfg.n_kv_heads, cfg.hd)
-        v_stage = layer_kv[1, tables].reshape(
-            n_seg, smax, cfg.n_kv_heads, cfg.hd)
+        k_stage = _stage(layer_kv[0], tables)
+        v_stage = _stage(layer_kv[1], tables)
         # regroup flat queries into the padded per-segment layout; the
         # extra row n_seg absorbs padding tokens
         q_pad = jnp.zeros((n_seg + 1, sq) + q.shape[2:], q.dtype)
@@ -296,6 +310,53 @@ def prefill_packed(cfg: ArchConfig, params, pool_kv, tokens, positions,
     x_last = x[0, last_idx]                                # (S, d)
     logits = jnp.einsum("sd,vd->sv", x_last, params["lm_head"])
     return logits, pool_kv
+
+
+def pack_prefill(chunks, bs: int):
+    """Host-side packing of ``prefill_packed``'s inputs.
+
+    ``chunks``: one ``(tokens, ctx, table)`` per request — the chunk's
+    token ids, the tokens already cached before it, and the request's
+    physical block table (covering ctx + len(tokens)).  Returns
+    ``(arrays, smax, sq)`` where ``arrays`` are the numpy operands
+    ``tokens, positions, q_rows, q_cols, scatter_blocks, scatter_slots,
+    tables, ctx_lens, last_idx`` in ``prefill_packed``'s order."""
+    n_seg = len(chunks)
+    sq = chunk_bucket(max(len(t) for t, _, _ in chunks))
+    smax = chunk_bucket(max(ctx + len(t) for t, ctx, _ in chunks))
+    smax = -(-smax // bs) * bs
+    maxp = smax // bs
+    t_b = flat_bucket(sum(len(t) for t, _, _ in chunks))
+    s_b = seg_bucket(n_seg)
+
+    tokens = np.zeros((1, t_b), np.int32)
+    positions = np.zeros((1, t_b), np.int32)
+    q_rows = np.full((t_b,), s_b, np.int32)       # padding -> extra row
+    q_cols = np.zeros((t_b,), np.int32)
+    sblocks = np.zeros((t_b,), np.int32)          # padding -> null block 0
+    sslots = np.zeros((t_b,), np.int32)
+    tables = np.zeros((s_b, maxp), np.int32)
+    ctx_lens = np.zeros((s_b,), np.int32)
+    last_idx = np.zeros((s_b,), np.int32)
+    off = 0
+    for i, (toks, ctx, table) in enumerate(chunks):
+        n = len(toks)
+        tokens[0, off:off + n] = toks
+        pos = np.arange(ctx, ctx + n, dtype=np.int32)
+        positions[0, off:off + n] = pos
+        q_rows[off:off + n] = i
+        q_cols[off:off + n] = np.arange(n, dtype=np.int32)
+        t = np.asarray(table, np.int32)
+        sblocks[off:off + n] = t[pos // bs]
+        sslots[off:off + n] = pos % bs
+        k = min(len(t), maxp)
+        tables[i, :k] = t[:k]
+        ctx_lens[i] = ctx
+        last_idx[i] = off + n - 1
+        off += n
+    arrays = (tokens, positions, q_rows, q_cols, sblocks, sslots, tables,
+              ctx_lens, last_idx)
+    return arrays, smax, sq
 
 
 def bucket(n: int, buckets=(16, 32, 64, 128, 256, 512, 1024, 2048)) -> int:

@@ -27,6 +27,9 @@ positions are worth verifying.
 """
 from __future__ import annotations
 
+from typing import Optional
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -41,10 +44,13 @@ GAP_PREFILL = 8
 
 class DraftRunner:
     def __init__(self, cfg: ArchConfig, params, *, num_blocks: int = 512,
-                 block_size: int = 16, max_ctx: int = 1024):
+                 block_size: int = 16, max_ctx: int = 1024,
+                 device: Optional[jax.Device] = None):
         self.cfg = cfg
-        self.params = params
-        self.pool = PagedKVPool(cfg, num_blocks, block_size)
+        # the draft lives on its target replica's device
+        self.params = (params if device is None
+                       else jax.device_put(params, device))
+        self.pool = PagedKVPool(cfg, num_blocks, block_size, device=device)
         self.max_ctx = max_ctx
         # rid -> leading draft-KV positions that match the target stream
         self.ctx: dict[int, int] = {}

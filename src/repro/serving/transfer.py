@@ -69,12 +69,15 @@ class TransferDone:
 class TransferWorker:
     """One background thread owning both copy lanes of one engine."""
 
-    def __init__(self, max_staged: int = 2):
+    def __init__(self, max_staged: int = 2,
+                 device: Optional[jax.Device] = None):
         self.max_staged = max_staged
+        # staged H2D buffers land on the engine's device (None: default)
+        self.device = device
         self._jobs: "queue.Queue[Optional[tuple]]" = queue.Queue()
         self._lock = threading.Lock()
         self._done: list[TransferDone] = []
-        # rid -> (epoch, n_blocks, (n, L, 2, bs, Hkv, hd) device array)
+        # rid -> (epoch, n_blocks, (n, L, 2, Hkv, bs, hd) device array)
         self._staged: dict[int, tuple[int, int, object]] = {}
         # rids with a staging job enqueued but not yet landed: reserves the
         # slot so the engine's per-step hints don't enqueue duplicates
@@ -94,7 +97,7 @@ class TransferWorker:
 
     def offload(self, rid: int, epoch: int, logical: list[int],
                 gathered) -> None:
-        """Enqueue a D2H mirror: ``gathered`` is the (n, L, 2, bs, Hkv, hd)
+        """Enqueue a D2H mirror: ``gathered`` is the (n, L, 2, Hkv, bs, hd)
         device-side snapshot of the blocks (already dispatched)."""
         self._ensure_started()
         self._jobs.put(("d2h", rid, epoch, logical, gathered))
@@ -165,7 +168,8 @@ class TransferWorker:
                 self._jobs.task_done()
                 break
             try:
-                self._execute(job)
+                with jax.default_device(self.device):
+                    self._execute(job)
             except Exception:
                 # never kill the lane — the engine's synchronous fallback
                 # stays correct — but never swallow silently either: report

@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import asyncio
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Iterable, Optional
 
 import numpy as np
@@ -37,6 +37,8 @@ class ReplayReport:
     n_rejected: int
     wall: float                 # wall-clock seconds the replay took
     speed: float                # trace-time compression factor
+    # frontend mode: the submitted RequestStreams, in trace order
+    streams: list = field(default_factory=list)
 
     def row(self) -> dict:
         d = {"submitted": self.n_submitted, "completed": self.n_completed,
@@ -185,7 +187,7 @@ async def replay_frontend(frontend, requests: Iterable[Request], vocab: int,
         summary=summarize(clones, w_p=w_p, w_d=w_d),
         n_submitted=len(streams),
         n_completed=sum(1 for s in streams if s.complete),
-        n_rejected=rejected, wall=wall, speed=speed)
+        n_rejected=rejected, wall=wall, speed=speed, streams=streams)
 
 
 def replay_sim(cluster, requests: list[Request], *, w_p: float = 1.0,

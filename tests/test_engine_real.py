@@ -134,3 +134,77 @@ def test_service_elastic_add_and_graceful_remove():
     svc.remove_instance(i0, drain=True)           # graceful scale down
     svc.serve_until_drained()
     assert len(svc.finished) == 6
+
+
+def _committed_to(arr, device) -> bool:
+    return arr.committed and arr.devices() == {device}
+
+
+def test_engine_pinned_to_device_matches_reference():
+    """``device=`` commits params and pool to that device, keeps them there
+    through preemption and reload, and changes no token."""
+    dev = jax.devices()[0]
+    eng = Engine(CFG, PARAMS, EngineConfig(eta=1.0, w_p=4.0, tau=1e9),
+                 make_policy("slidebatching"), num_blocks=10, block_size=16,
+                 max_ctx=256, device=dev)
+    assert all(_committed_to(a, dev) for a in jax.tree.leaves(eng.params))
+    assert _committed_to(eng.pool.kv, dev)
+    reqs = [submit(eng, 40, 4) for _ in range(4)]
+    refs = {r.rid: greedy_reference(p, 4) for r, p in reqs}
+    eng.run_until_drained(max_iters=400)
+    assert eng.stats.evictions > 0, "test needs actual preemption pressure"
+    for r, _ in reqs:
+        assert eng.outputs[r.rid] == refs[r.rid]
+    assert _committed_to(eng.pool.kv, dev)
+    eng.kill()
+
+
+PINNED_REPLICAS = """
+import jax, numpy as np
+from repro.configs import get_smoke
+from repro.core import EngineConfig, Request, SLO, make_policy
+from repro.models import init_params
+from repro.serving import Engine
+
+cfg = get_smoke("qwen1_5_0_5b")
+params = init_params(cfg, jax.random.PRNGKey(0))
+devs = jax.devices()
+assert len(devs) == 2
+prompts = [np.random.default_rng(i).integers(1, cfg.vocab, 40).astype(np.int32)
+           for i in range(4)]
+outs = []
+for dev in (None, devs[0], devs[1]):
+    eng = Engine(cfg, params, EngineConfig(eta=1.0, w_p=4.0, tau=1e9),
+                 make_policy("slidebatching"), num_blocks=10, block_size=16,
+                 max_ctx=256, device=dev)
+    rids = []
+    for p in prompts:
+        r = Request(prompt_len=40, output_len=4, arrival=0.0,
+                    slo=SLO(3600.0, 3600.0))
+        eng.add_request(r, p)
+        rids.append(r.rid)
+    eng.run_until_drained(max_iters=400)
+    assert eng.stats.evictions > 0
+    if dev is not None:
+        for a in jax.tree.leaves(eng.params) + [eng.pool.kv]:
+            assert a.committed and a.devices() == {dev}, (a.devices(), dev)
+    outs.append([eng.outputs[r] for r in rids])
+    eng.kill()
+assert outs[0] == outs[1] == outs[2], outs
+print("PINNED_OK")
+"""
+
+
+def test_replicas_pinned_to_separate_devices():
+    """Two virtual CPU devices in a subprocess: a replica pinned to the
+    second device keeps every array there and streams the same tokens as
+    the default-device replica."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    out = subprocess.run([sys.executable, "-c", PINNED_REPLICAS], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "PINNED_OK" in out.stdout

@@ -330,3 +330,29 @@ def test_replay_sim_deterministic_and_per_priority():
     assert set(a.per_priority) == {1, 2}
     for m in a.per_priority.values():
         assert 0.0 <= m["slo"] <= 1.0 and m["tdg_ratio"] >= 0.0
+
+
+def test_driver_exception_reaches_the_client():
+    """A replica whose step raises must not leave its streams waiting
+    forever: the error is recorded on the frontend and raised from every
+    stream that replica held."""
+    async def run():
+        fe = make_frontend(n_replicas=1, max_inflight=8)
+        eng = next(iter(fe.engines.values()))
+
+        def boom():
+            raise ValueError("step exploded")
+        eng.step = boom
+        await fe.start()
+        r = Request(prompt_len=8, output_len=2, arrival=0.0, slo=SLO_LOOSE)
+        stream = await fe.submit(r, RNG.integers(1, CFG.vocab, 8))
+        with pytest.raises(RuntimeError, match="step exploded"):
+            await asyncio.wait_for(stream.collect(), 60)
+        assert await fe.drain(timeout=5)
+        await fe.stop()
+        return fe
+
+    fe = asyncio.run(run())
+    (iid, err), = fe.driver_errors.items()
+    assert isinstance(err, ValueError)
+    assert fe.drivers[iid].error is err

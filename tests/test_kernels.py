@@ -35,8 +35,8 @@ def test_paged_decode_attention_sweep(dtype, b, h, hkv, hd, page, maxp):
     ks = jax.random.split(KEY, 4)
     P = maxp * b + 3
     q = jax.random.normal(ks[0], (b, h, hd), dtype)
-    kp = jax.random.normal(ks[1], (P, page, hkv, hd), dtype)
-    vp = jax.random.normal(ks[2], (P, page, hkv, hd), dtype)
+    kp = jax.random.normal(ks[1], (P, hkv, page, hd), dtype)
+    vp = jax.random.normal(ks[2], (P, hkv, page, hd), dtype)
     bt = jax.random.randint(ks[3], (b, maxp), 0, P)
     lens = jnp.asarray(
         np.random.default_rng(0).integers(1, maxp * page + 1, b), jnp.int32)
@@ -52,8 +52,8 @@ def test_paged_decode_length_edge_cases():
     ks = jax.random.split(KEY, 4)
     P = 8
     q = jax.random.normal(ks[0], (b, h, hd))
-    kp = jax.random.normal(ks[1], (P, page, hkv, hd))
-    vp = jax.random.normal(ks[2], (P, page, hkv, hd))
+    kp = jax.random.normal(ks[1], (P, hkv, page, hd))
+    vp = jax.random.normal(ks[2], (P, hkv, page, hd))
     bt = jax.random.randint(ks[3], (b, maxp), 0, P)
     lens = jnp.asarray([1, maxp * page], jnp.int32)
     out = paged_decode_attention(q, kp, vp, bt, lens)
@@ -70,8 +70,8 @@ def test_paged_decode_length_edge_cases():
 def test_chunked_prefill_sweep(dtype, b, sq, smax, h, hkv, hd, kvb):
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (b, sq, h, hd), dtype)
-    kc = jax.random.normal(ks[1], (b, smax, hkv, hd), dtype)
-    vc = jax.random.normal(ks[2], (b, smax, hkv, hd), dtype)
+    kc = jax.random.normal(ks[1], (b, hkv, smax, hd), dtype)
+    vc = jax.random.normal(ks[2], (b, hkv, smax, hd), dtype)
     rng = np.random.default_rng(1)
     lens = jnp.asarray(rng.integers(sq, smax + 1, b), jnp.int32)
     out = chunked_prefill_attention(q, kc, vc, lens, kv_block=kvb)
@@ -85,8 +85,8 @@ def test_chunked_prefill_fresh_prompt():
     b, sq, h, hkv, hd = 2, 12, 4, 2, 16
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (b, sq, h, hd))
-    kc = jax.random.normal(ks[1], (b, sq, hkv, hd))
-    vc = jax.random.normal(ks[2], (b, sq, hkv, hd))
+    kc = jax.random.normal(ks[1], (b, hkv, sq, hd))
+    vc = jax.random.normal(ks[2], (b, hkv, sq, hd))
     lens = jnp.full((b,), sq, jnp.int32)
     out = chunked_prefill_attention(q, kc, vc, lens, kv_block=8)
     ref = chunked_prefill_attention_ref(q, kc, vc, lens)
@@ -95,7 +95,7 @@ def test_chunked_prefill_fresh_prompt():
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_block_gather(dtype):
-    pool = jax.random.normal(KEY, (32, 16, 2, 8), dtype)
+    pool = jax.random.normal(KEY, (32, 2, 16, 8), dtype)    # (P, Hkv, bs, hd)
     idx = jnp.asarray([3, 31, 0, 3, 17], jnp.int32)
     out = block_gather(pool, idx)
     np.testing.assert_array_equal(np.asarray(out),
@@ -110,7 +110,7 @@ def test_block_gather(dtype):
 def test_kv_quant_bitwise_vs_ref(n, layers, bs, hkv, hd, amp):
     """Quantize AND dequantize kernels are bitwise-equal to the oracles
     (elementwise ops + exact reductions only)."""
-    x = jax.random.normal(KEY, (n, layers, 2, bs, hkv, hd)) * amp
+    x = jax.random.normal(KEY, (n, layers, 2, hkv, bs, hd)) * amp
     x = x.at[0, 0].set(jnp.zeros_like(x[0, 0]))   # all-zero plane: scale 0
     vals, scales = kv_block_quantize(x)
     vr, sr = kv_block_quantize_ref(x)
@@ -126,7 +126,7 @@ def test_kv_quant_bitwise_vs_ref(n, layers, bs, hkv, hd, amp):
 def test_kv_quant_roundtrip_error_bound():
     """The documented int8 bound: per element |x - deq(quant(x))| <=
     scale/2 of its (block, layer, k|v) plane, zero planes exact."""
-    x = jax.random.normal(KEY, (4, 3, 2, 8, 2, 16)) * 7.0
+    x = jax.random.normal(KEY, (4, 3, 2, 2, 8, 16)) * 7.0
     x = x.at[1].set(jnp.zeros_like(x[1]))
     vals, scales = kv_block_quantize(x)
     deq = np.asarray(kv_block_dequantize(vals, scales))
@@ -148,13 +148,13 @@ def test_kernel_consistency_with_model_decode():
     ks = jax.random.split(KEY, 4)
     P = b * maxp + 1
     q = jax.random.normal(ks[0], (b, h, hd))
-    kp = jax.random.normal(ks[1], (P, page, hkv, hd))
-    vp = jax.random.normal(ks[2], (P, page, hkv, hd))
+    kp = jax.random.normal(ks[1], (P, hkv, page, hd))
+    vp = jax.random.normal(ks[2], (P, hkv, page, hd))
     bt = jnp.arange(1, 1 + b * maxp, dtype=jnp.int32).reshape(b, maxp)
     lens = jnp.asarray([13, 29], jnp.int32)
     out = paged_decode_attention(q, kp, vp, bt, lens)
-    # build the contiguous equivalent
-    k_lin = kp[bt].reshape(b, maxp * page, hkv, hd)
-    v_lin = vp[bt].reshape(b, maxp * page, hkv, hd)
+    # build the contiguous (token-major) equivalent of the head-major pages
+    k_lin = kp[bt].transpose(0, 1, 3, 2, 4).reshape(b, maxp * page, hkv, hd)
+    v_lin = vp[bt].transpose(0, 1, 3, 2, 4).reshape(b, maxp * page, hkv, hd)
     ref = model_decode(q[:, None], k_lin, v_lin, lens)[:, 0]
     np.testing.assert_allclose(out, ref, atol=2e-5)

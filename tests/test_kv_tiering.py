@@ -17,8 +17,8 @@ pytestmark = pytest.mark.slow
 
 RNG = np.random.default_rng(11)
 
-# synthetic block shape (L, 2, bs, Hkv, hd) — small but full-rank
-BSHAPE = (2, 2, 4, 1, 4)
+# synthetic block shape (L, 2, Hkv, bs, hd) — small but full-rank
+BSHAPE = (2, 2, 1, 4, 4)
 
 
 def blk():

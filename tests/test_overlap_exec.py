@@ -62,8 +62,8 @@ def test_packed_kernel_bitwise_matches_per_segment():
     rng = np.random.default_rng(0)
     s, sq, h, hkv, hd, smax = 3, 8, 4, 2, 16, 64
     q = rng.standard_normal((s, sq, h, hd)).astype(np.float32)
-    k = rng.standard_normal((s, smax, hkv, hd)).astype(np.float32)
-    v = rng.standard_normal((s, smax, hkv, hd)).astype(np.float32)
+    k = rng.standard_normal((s, hkv, smax, hd)).astype(np.float32)
+    v = rng.standard_normal((s, hkv, smax, hd)).astype(np.float32)
     ctx = np.array([0, 16, 40], np.int32)
     packed = np.asarray(packed_prefill_attention(
         jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(ctx),
@@ -149,7 +149,7 @@ def test_pool_offload_drop_reload_roundtrip_batched():
     pool.alloc(1, 3)
     rng = np.random.default_rng(1)
     vals = rng.standard_normal(
-        (CFG.n_layers, 2, 3, 4, CFG.n_kv_heads, CFG.hd)).astype(np.float32)
+        (CFG.n_layers, 2, 3, CFG.n_kv_heads, 4, CFG.hd)).astype(np.float32)
     phys = list(pool.tables[1])
     pool.kv = pool.kv.at[:, :, jnp.asarray(phys)].set(jnp.asarray(vals))
     pool.offload_blocks(1, [0, 1, 2])                    # one device fetch

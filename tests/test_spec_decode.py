@@ -150,8 +150,8 @@ def test_packed_verify_kernel_contract():
     n_pages, maxp, n_seg = 24, 3, 3
     depth = 2
     k1, k2, k3 = jax.random.split(key, 3)
-    k_pages = jax.random.normal(k1, (n_pages, page, hkv, hd), jax.numpy.float32)
-    v_pages = jax.random.normal(k2, (n_pages, page, hkv, hd), jax.numpy.float32)
+    k_pages = jax.random.normal(k1, (n_pages, hkv, page, hd), jax.numpy.float32)
+    v_pages = jax.random.normal(k2, (n_pages, hkv, page, hd), jax.numpy.float32)
     rng = np.random.default_rng(5)
     tables = rng.permutation(np.arange(1, n_pages))[:n_seg * maxp]
     tables = tables.reshape(n_seg, maxp).astype(np.int32)
