@@ -65,7 +65,6 @@ class Request:
     host_prefilled: int = 0      # prompt tokens whose KV was computed but
                                  # currently lives in HOST memory (evicted)
     out_times: list[float] = field(default_factory=list)  # emission stamps
-    first_scheduled: Optional[float] = None
     finish_time: Optional[float] = None
     preemptions: int = 0
     starving: bool = False       # anti-starvation promotion flag

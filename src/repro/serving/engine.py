@@ -39,7 +39,6 @@ import logging
 import queue
 import threading
 import time
-from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -47,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..core.batching import (BatchPlan, EngineConfig, SchedView,
                              compute_remaining, evict_for_space,
                              needed_context)
@@ -154,10 +154,6 @@ class StepEvent:
     est_time: float
     prefill_done: tuple = ()     # rids whose first token just came out
     finished: tuple = ()         # rids fully generated this step
-    # per-step transfer/overlap telemetry (§4.3 lanes made real)
-    offload_blocks: int = 0      # D2H completions drained this step
-    reload_blocks: int = 0       # H2D blocks restored for this batch
-    transfer_wait: float = 0.0   # seconds the step stalled on sync copies
 
 
 @dataclass
@@ -174,7 +170,6 @@ class EngineStats:
     offload_blocks: int = 0        # async D2H blocks landed on host
     staged_hits: int = 0           # reloads served from pre-staged buffers
     staged_misses: int = 0         # reloads that fell back to a sync copy
-    transfer_wait_s: float = 0.0   # total step time stalled on sync copies
     transfer_failures: int = 0     # background copies that raised (fell
     # back to the synchronous path; first one is logged by the worker)
     t_block_measured: float = 0.0  # EWMA per-block copy time (closed loop)
@@ -200,9 +195,6 @@ class EngineStats:
     spec_rejected: int = 0         # proposals refuted (== proposed - accepted)
     draft_launches: int = 0        # draft-model jit calls (prefill + rounds)
     spec_depth_hist: dict = field(default_factory=dict)  # depth -> entries
-    # bounded: long-lived replicas must not grow without limit
-    batch_latencies: deque = field(
-        default_factory=lambda: deque(maxlen=512))
 
 
 class Engine:
@@ -385,21 +377,22 @@ class Engine:
             logical = [bi for bi in range(start, start + n) if bi < len(t)]
             if not logical:
                 continue
-            if self.pool.tier.prefer_cold(len(logical)):
-                # this mirror would land demote-bound in the cold tier:
-                # quantize on device so the D2H wire is int8 (~4x less)
-                gathered = self.pool.gather_blocks_quantized(rid, logical)
-            else:
-                gathered = self.pool.gather_blocks(rid, logical)
-            self.worker.offload(rid, epoch, logical, gathered)
+            with tracing.span("kv.offload", rid=rid):
+                if self.pool.tier.prefer_cold(len(logical)):
+                    # this mirror would land demote-bound in the cold
+                    # tier: quantize on device so the D2H wire is int8
+                    gathered = self.pool.gather_blocks_quantized(rid,
+                                                                 logical)
+                else:
+                    gathered = self.pool.gather_blocks(rid, logical)
+                self.worker.offload(rid, epoch, logical, gathered)
 
-    def _drain_transfers(self) -> int:
+    def _drain_transfers(self) -> None:
         """Collect background-copy completions; feed the accounting lanes
         (real transfers replace the virtual clock) and the measured-
         throughput side of the adaptive copy budget."""
         if self.worker is None:
-            return 0
-        landed = 0
+            return
         for d in self.worker.drain():
             if d.kind == "d2h" and d.rid in self._handoff_wait:
                 # handoff export riding the D2H lane: the local leg is
@@ -455,14 +448,12 @@ class Engine:
                 self.pool.host_store(d.rid, d.blocks)
                 self.bm.note_offload_complete(d.rid, d.n_blocks)
                 self.stats.offload_blocks += d.n_blocks
-                landed += d.n_blocks
             if not d.quantized:
                 # int8-wire copies are excluded: the copy budget scales
                 # them by COLD_WIRE_RATIO on top of the fp32 t_block,
                 # so folding their samples in would count the 4x twice
                 self.bm.observe_transfer(d.n_blocks, d.seconds)
                 self.stats.t_block_measured = self.bm.t_block
-        return landed
 
     def _prefetch_reloads(self) -> None:
         """Hint the H2D staging lane: evicted requests near the head of the
@@ -697,11 +688,16 @@ class Engine:
     def step(self) -> Optional[dict]:
         if not self.alive:
             return None
+        with tracing.span("engine.step"):
+            return self._step()
+
+    def _step(self) -> Optional[dict]:
         if self._wall_epoch is not None:
             self.now = max(self.now, time.monotonic() - self._wall_epoch)
-        offload_landed = self._drain_transfers()
-        self.bm.complete_offloads(self.now)
-        self._sync_tier_state()
+        with tracing.span("kv.drain"):
+            self._drain_transfers()
+            self.bm.complete_offloads(self.now)
+            self._sync_tier_state()
         if self.role == "prefill":
             # straggler exports (e.g. a full-prompt cache hit made the
             # request decode-ready without any prefill work this step) —
@@ -709,57 +705,23 @@ class Engine:
             self._collect_handoffs()
         view = SchedView(self.queue, self.bm, self.est, self.eng_cfg,
                          self.now)
-        plan = self.policy.form_batch(view)
+        with tracing.span("sched.form_batch"):
+            plan = self.policy.form_batch(view)
         if not plan.entries:
             # evictions can outlive a failed admission round: keep the
             # pool consistent with the accounting before going idle, and
             # use the idle gap to stage likely reloads
             if plan.evictions:
-                self._sync_pool_with_bm(plan)
+                with tracing.span("kv.sync"):
+                    self._sync_pool_with_bm(plan)
             self._offload_directives.clear()
-            self._prefetch_reloads()
+            with tracing.span("kv.prefetch"):
+                self._prefetch_reloads()
             return None
         t0 = time.monotonic()
-        self._sync_pool_with_bm(plan)
-
-        # reload data for requests whose plan restored host blocks; prefer
-        # the background lane's pre-staged buffers (the H2D copy already
-        # landed), falling back to a synchronous batched copy
-        step_reload, step_wait = 0, 0.0
-        for e in plan.entries:
-            s = self.bm.state(e.req)
-            hb = self.pool.host_blocks(e.req.rid)
-            dev_blocks_needed = blocks_for(s.dev_tokens, self.bm.block_size)
-            have = len(self.pool.tables.get(e.req.rid, []))
-            # only copy what apply_reload promised (restore_pending): with
-            # async mirroring, host entries also exist for live
-            # device-resident requests, so ``hb > 0`` alone would trigger
-            # phantom reloads on every block-boundary growth
-            if s.restore_pending > 0 and have < dev_blocks_needed and hb:
-                n = min(s.restore_pending, dev_blocks_needed - have)
-                s.restore_pending = 0
-                staged = (self.worker.take_staged(
-                    e.req.rid, self._epoch.get(e.req.rid, 0))
-                    if self.worker is not None else None)
-                if staged is not None and staged[0] > 0:
-                    # ``n`` also counts blocks this step will write fresh
-                    # (grown chunk/decode tokens); the staged buffer covers
-                    # exactly the restorable host prefix — consume what it
-                    # has, the rest is new capacity allocated at exec time
-                    # (same semantics as reload_blocks, which stops at the
-                    # first non-host block)
-                    self.pool.reload_from_device(e.req.rid, staged[1],
-                                                 min(n, staged[0]))
-                    self.stats.staged_hits += 1
-                else:
-                    tr0 = time.monotonic()
-                    self.pool.reload_blocks(e.req.rid, n)
-                    step_wait += time.monotonic() - tr0
-                    if self.worker is not None:
-                        self.stats.staged_misses += 1
-                self.stats.reload_blocks += n
-                step_reload += n
-        self.stats.transfer_wait_s += step_wait
+        with tracing.span("kv.sync"):
+            self._sync_pool_with_bm(plan)
+        self._reload_for(plan)
 
         decode_entries = [e for e in plan.entries if not e.is_prefill]
         prefill_entries = [e for e in plan.entries if e.is_prefill]
@@ -784,11 +746,69 @@ class Engine:
         else:
             self.now += latency
         self.stats.iterations += 1
-        self.stats.batch_latencies.append(latency)
         self._profile.append((plan.work_items(), latency))
         if len(self._profile) >= self.refit_every:
-            self._refit()
+            with tracing.span("est.refit"):
+                self._refit()
 
+        with tracing.span("engine.finish"):
+            finished = self._release_finished()
+        if self.role == "prefill":
+            # export every request whose prefill leg just completed (the
+            # gather runs before the proactive-mirror dispatch below, so
+            # the exported KV ships exactly once)
+            self._collect_handoffs()
+        # all K/V written and finished requests released — snapshot +
+        # enqueue the proactive D2H mirrors the policy scheduled (the
+        # released requests' directives drop out via their empty tables,
+        # sparing a full dead-request gather), then stage likely reloads
+        self._dispatch_offloads()
+        with tracing.span("kv.prefetch"):
+            self._prefetch_reloads()
+        return {"emitted": emitted, "finished": finished,
+                "latency": latency, "plan": plan}
+
+    def _reload_for(self, plan: BatchPlan) -> None:
+        """Reload data for requests whose plan restored host blocks; prefer
+        the background lane's pre-staged buffers (the H2D copy already
+        landed), falling back to a synchronous batched copy."""
+        for e in plan.entries:
+            s = self.bm.state(e.req)
+            hb = self.pool.host_blocks(e.req.rid)
+            dev_blocks_needed = blocks_for(s.dev_tokens, self.bm.block_size)
+            have = len(self.pool.tables.get(e.req.rid, []))
+            # only copy what apply_reload promised (restore_pending): with
+            # async mirroring, host entries also exist for live
+            # device-resident requests, so ``hb > 0`` alone would trigger
+            # phantom reloads on every block-boundary growth
+            if not (s.restore_pending > 0 and have < dev_blocks_needed
+                    and hb):
+                continue
+            n = min(s.restore_pending, dev_blocks_needed - have)
+            s.restore_pending = 0
+            with tracing.span("kv.reload", rid=e.req.rid):
+                staged = (self.worker.take_staged(
+                    e.req.rid, self._epoch.get(e.req.rid, 0))
+                    if self.worker is not None else None)
+                if staged is not None and staged[0] > 0:
+                    # ``n`` also counts blocks this step will write fresh
+                    # (grown chunk/decode tokens); the staged buffer covers
+                    # exactly the restorable host prefix — consume what it
+                    # has, the rest is new capacity allocated at exec time
+                    # (same semantics as reload_blocks, which stops at the
+                    # first non-host block)
+                    self.pool.reload_from_device(e.req.rid, staged[1],
+                                                 min(n, staged[0]))
+                    self.stats.staged_hits += 1
+                else:
+                    self.pool.reload_blocks(e.req.rid, n)
+                    if self.worker is not None:
+                        self.stats.staged_misses += 1
+            self.stats.reload_blocks += n
+
+    def _release_finished(self) -> list[Request]:
+        """Release every finished request's blocks and per-request state;
+        returns them."""
         finished = [r for r in self.queue if r.phase == Phase.FINISHED]
         for r in finished:
             self.bm.release(r)
@@ -805,22 +825,7 @@ class Engine:
             self._seqs.pop(r.rid, None)
             self._seq_fill.pop(r.rid, None)
         self.queue = [r for r in self.queue if r.phase != Phase.FINISHED]
-        if self.role == "prefill":
-            # export every request whose prefill leg just completed (the
-            # gather runs before the proactive-mirror dispatch below, so
-            # the exported KV ships exactly once)
-            self._collect_handoffs()
-        # all K/V written and finished requests released — snapshot +
-        # enqueue the proactive D2H mirrors the policy scheduled (the
-        # released requests' directives drop out via their empty tables,
-        # sparing a full dead-request gather), then stage likely reloads
-        self._dispatch_offloads()
-        self._prefetch_reloads()
-        return {"emitted": emitted, "finished": finished,
-                "latency": latency, "plan": plan,
-                "offload_blocks": offload_landed,
-                "reload_blocks": step_reload,
-                "transfer_wait": step_wait}
+        return finished
 
     # ------------------------------------------------------------------
     # decode execution
@@ -830,42 +835,47 @@ class Engine:
         or the logits fallback)."""
         rids = [e.req.rid for e in decode_entries]
         nb = len(decode_entries)
-        for e in decode_entries:
-            self.pool.ensure_capacity(e.req.rid, e.l_kv + 1)
-            if self.pool.ensure_writable(e.req.rid,
-                                         e.l_kv // self.pool.block_size):
-                self.bm.note_fork(e.req)
-                self.stats.cow_forks += 1
-        maxp = max(len(self.pool.tables[r]) for r in rids)
-        if self.fused_decode:
-            # pad batch/table to shape buckets (extra rows: token 0,
-            # len 0, null-block table) and fetch only the (B,) argmax
-            b_b = model_exec.seg_bucket(nb)
-            maxp_b = model_exec.table_bucket(maxp)
-            lens = np.zeros(b_b, np.int32)
-            lens[:nb] = [e.l_kv for e in decode_entries]
-            last = np.zeros(b_b, np.int32)
-            last[:nb] = [self._last_token(e.req)
-                         for e in decode_entries]
-            table = self.pool.table_array(rids, maxp=maxp_b, rows=b_b)
-            toks, self.pool.kv = model_exec.decode_step(
-                self.cfg, self.params, self.pool.kv,
-                jnp.asarray(last), table, jnp.asarray(lens))
-            nxt = np.asarray(toks)[:nb]
-        else:
-            lens = np.array([e.l_kv for e in decode_entries], np.int32)
-            table = self.pool.table_array(rids, maxp=maxp)
-            last = np.array(
-                [self._last_token(e.req) for e in decode_entries],
-                np.int32)
-            logits, self.pool.kv = model_exec.decode_batch(
-                self.cfg, self.params, self.pool.kv, jnp.asarray(last),
-                table, jnp.asarray(lens))
-            nxt = np.asarray(jnp.argmax(logits, -1))
+        with tracing.span("exec.decode"):
+            with tracing.span("exec.stage"):
+                for e in decode_entries:
+                    self.pool.ensure_capacity(e.req.rid, e.l_kv + 1)
+                    if self.pool.ensure_writable(
+                            e.req.rid, e.l_kv // self.pool.block_size):
+                        self.bm.note_fork(e.req)
+                        self.stats.cow_forks += 1
+                maxp = max(len(self.pool.tables[r]) for r in rids)
+                if self.fused_decode:
+                    # pad batch/table to shape buckets (extra rows: token
+                    # 0, len 0, null-block table) and fetch only the (B,)
+                    # argmax
+                    b_b = model_exec.seg_bucket(nb)
+                    maxp = model_exec.table_bucket(maxp)
+                else:
+                    b_b = nb
+                lens = np.zeros(b_b, np.int32)
+                lens[:nb] = [e.l_kv for e in decode_entries]
+                last = np.zeros(b_b, np.int32)
+                last[:nb] = [self._last_token(e.req)
+                             for e in decode_entries]
+                table = self.pool.table_array(rids, maxp=maxp, rows=b_b)
+                last, lens = jnp.asarray(last), jnp.asarray(lens)
+            with tracing.span("exec.launch"):
+                if self.fused_decode:
+                    toks, self.pool.kv = model_exec.decode_step(
+                        self.cfg, self.params, self.pool.kv, last, table,
+                        lens)
+                else:
+                    logits, self.pool.kv = model_exec.decode_batch(
+                        self.cfg, self.params, self.pool.kv, last, table,
+                        lens)
+                    toks = jnp.argmax(logits, -1)
+            with tracing.span("exec.wait"):
+                nxt = np.asarray(toks)[:nb]
         self.stats.decode_launches += 1
         self.stats.host_syncs += 1
-        for e, tok in zip(decode_entries, nxt):
-            self._emit(e.req, int(tok), emitted)
+        with tracing.span("engine.emit"):
+            for e, tok in zip(decode_entries, nxt):
+                self._emit(e.req, int(tok), emitted)
 
     def _run_decode_spec(self, decode_entries: list, emitted: list) -> None:
         """Speculative decode: the draft proposes up to ``e.depth`` tokens
@@ -878,81 +888,90 @@ class Engine:
         kernels/spec_verify.py).  Depth was capped at admission to the
         current block's remainder, so all speculative writes land in
         blocks the +1-token growth already reserved."""
-        for e in decode_entries:
-            self.pool.ensure_capacity(e.req.rid, e.l_kv + 1 + e.depth)
-            if self.pool.ensure_writable(e.req.rid,
-                                         e.l_kv // self.pool.block_size):
-                self.bm.note_fork(e.req)
-                self.stats.cow_forks += 1
-        launches0 = self.draft.launches
-        syncs0 = self.draft.syncs
-        items = [(e.req.rid, self._seq_view(e.req), e.depth)
-                 for e in decode_entries if e.depth > 0]
-        proposals = self.draft.propose(items) if items else {}
-        self.stats.draft_launches += self.draft.launches - launches0
-        self.stats.host_syncs += self.draft.syncs - syncs0
-        for e in decode_entries:
-            if e.depth > 0 and e.req.rid not in proposals:
-                e.depth = 0      # draft pool exhausted: plain decode row
+        with tracing.span("exec.decode"):
+            for e in decode_entries:
+                self.pool.ensure_capacity(e.req.rid, e.l_kv + 1 + e.depth)
+                if self.pool.ensure_writable(e.req.rid,
+                                             e.l_kv // self.pool.block_size):
+                    self.bm.note_fork(e.req)
+                    self.stats.cow_forks += 1
+            launches0 = self.draft.launches
+            syncs0 = self.draft.syncs
+            items = [(e.req.rid, self._seq_view(e.req), e.depth)
+                     for e in decode_entries if e.depth > 0]
+            proposals = self.draft.propose(items) if items else {}
+            self.stats.draft_launches += self.draft.launches - launches0
+            self.stats.host_syncs += self.draft.syncs - syncs0
+            for e in decode_entries:
+                if e.depth > 0 and e.req.rid not in proposals:
+                    e.depth = 0    # draft pool exhausted: plain decode row
 
-        # pack one verify row per (request, draft position); tables stay
-        # compact — one row per REQUEST — addressed via row_seg.  The
-        # segment bucket reserves one extra all-zero row so padding rows'
-        # K/V write lands in the null block (decode_step convention).
-        rids = [e.req.rid for e in decode_entries]
-        n_seg = len(decode_entries)
-        rows: list[tuple] = []   # (entry index, token)
-        for i, e in enumerate(decode_entries):
-            rows.append((i, self._last_token(e.req)))
-            for t in proposals.get(e.req.rid, [])[:e.depth]:
-                rows.append((i, t))
-        n_rows = len(rows)
-        r_b = model_exec.seg_bucket(n_rows)
-        s_b = model_exec.seg_bucket(n_seg + 1)
-        maxp = max(len(self.pool.tables[r]) for r in rids)
-        maxp_b = model_exec.table_bucket(maxp)
-        tokens = np.zeros(r_b, np.int32)
-        lens = np.zeros(r_b, np.int32)
-        row_seg = np.full(r_b, n_seg, np.int32)   # padding -> zero table row
-        starts = np.zeros(n_seg, np.int32)
-        prev = -1
-        for ri, (i, tok) in enumerate(rows):
-            if i != prev:
-                starts[i] = ri
-                prev = i
-            tokens[ri] = tok
-            lens[ri] = decode_entries[i].l_kv + (ri - starts[i])
-            row_seg[ri] = i
-        tables = self.pool.table_array(rids, maxp=maxp_b, rows=s_b)
-        toks, self.pool.kv = model_exec.verify_step(
-            self.cfg, self.params, self.pool.kv, jnp.asarray(tokens),
-            tables, jnp.asarray(lens), jnp.asarray(row_seg))
+            with tracing.span("exec.stage"):
+                # pack one verify row per (request, draft position);
+                # tables stay compact — one row per REQUEST — addressed
+                # via row_seg.  The segment bucket reserves one extra
+                # all-zero row so padding rows' K/V write lands in the
+                # null block (decode_step convention).
+                rids = [e.req.rid for e in decode_entries]
+                n_seg = len(decode_entries)
+                rows: list[tuple] = []   # (entry index, token)
+                for i, e in enumerate(decode_entries):
+                    rows.append((i, self._last_token(e.req)))
+                    for t in proposals.get(e.req.rid, [])[:e.depth]:
+                        rows.append((i, t))
+                n_rows = len(rows)
+                r_b = model_exec.seg_bucket(n_rows)
+                s_b = model_exec.seg_bucket(n_seg + 1)
+                maxp = max(len(self.pool.tables[r]) for r in rids)
+                maxp_b = model_exec.table_bucket(maxp)
+                tokens = np.zeros(r_b, np.int32)
+                lens = np.zeros(r_b, np.int32)
+                # padding rows -> the zero table row
+                row_seg = np.full(r_b, n_seg, np.int32)
+                starts = np.zeros(n_seg, np.int32)
+                prev = -1
+                for ri, (i, tok) in enumerate(rows):
+                    if i != prev:
+                        starts[i] = ri
+                        prev = i
+                    tokens[ri] = tok
+                    lens[ri] = decode_entries[i].l_kv + (ri - starts[i])
+                    row_seg[ri] = i
+                tables = self.pool.table_array(rids, maxp=maxp_b, rows=s_b)
+                tokens, lens = jnp.asarray(tokens), jnp.asarray(lens)
+                row_seg = jnp.asarray(row_seg)
+            with tracing.span("exec.launch"):
+                toks, self.pool.kv = model_exec.verify_step(
+                    self.cfg, self.params, self.pool.kv, tokens, tables, lens,
+                    row_seg)
+            with tracing.span("exec.wait"):
+                out = np.asarray(toks)
+
         self.stats.decode_launches += 1
         self.stats.host_syncs += 1
-        out = np.asarray(toks)
-
-        for i, e in enumerate(decode_entries):
-            d = e.depth
-            g = out[starts[i]:starts[i] + d + 1]
-            props = proposals.get(e.req.rid, [])[:d]
-            a = 0
-            while a < d and props[a] == g[a]:
-                a += 1
-            for t in g[:a + 1]:
-                self._emit(e.req, int(t), emitted)
-            # bonus tokens advance context inside blocks the +1 growth
-            # already covers (depth <= block remainder at admission)
-            self.bm.state(e.req).dev_tokens += a
-            if d > 0:
-                self.draft.observe(e.req.rid, d, a)
-                accept = getattr(self.policy, "spec_accept", None)
-                if accept is not None:
-                    accept.update(d, a)
-            self.stats.spec_proposed += d
-            self.stats.spec_accepted += a
-            self.stats.spec_rejected += d - a
-            self.stats.spec_depth_hist[d] = \
-                self.stats.spec_depth_hist.get(d, 0) + 1
+        with tracing.span("engine.emit"):
+            for i, e in enumerate(decode_entries):
+                d = e.depth
+                g = out[starts[i]:starts[i] + d + 1]
+                props = proposals.get(e.req.rid, [])[:d]
+                a = 0
+                while a < d and props[a] == g[a]:
+                    a += 1
+                for t in g[:a + 1]:
+                    self._emit(e.req, int(t), emitted)
+                # bonus tokens advance context inside blocks the +1 growth
+                # already covers (depth <= block remainder at admission)
+                self.bm.state(e.req).dev_tokens += a
+                if d > 0:
+                    self.draft.observe(e.req.rid, d, a)
+                    accept = getattr(self.policy, "spec_accept", None)
+                    if accept is not None:
+                        accept.update(d, a)
+                self.stats.spec_proposed += d
+                self.stats.spec_accepted += a
+                self.stats.spec_rejected += d - a
+                self.stats.spec_depth_hist[d] = \
+                    self.stats.spec_depth_hist.get(d, 0) + 1
 
     # ------------------------------------------------------------------
     # prefill execution
@@ -992,24 +1011,31 @@ class Engine:
         into one flat token stream and executed in a single bucketed jit
         call — and each segment stages only the blocks it needs, instead
         of the engine-wide ``max_ctx`` span per chunk."""
-        for e in entries:
-            self._prepare_prefill(e)
-        chunks = [(self._seq_view(e.req)[e.l_kv:e.l_kv + e.n_tokens],
-                   e.l_kv, self.pool.tables[e.req.rid]) for e in entries]
-        arrays, smax, sq = model_exec.pack_prefill(chunks,
-                                                   self.pool.block_size)
-        logits, self.pool.kv = model_exec.prefill_packed(
-            self.cfg, self.params, self.pool.kv,
-            *(jnp.asarray(a) for a in arrays), smax, sq)
+        with tracing.span("exec.prefill"):
+            with tracing.span("exec.stage"):
+                for e in entries:
+                    self._prepare_prefill(e)
+                chunks = [(self._seq_view(e.req)[e.l_kv:e.l_kv + e.n_tokens],
+                           e.l_kv, self.pool.tables[e.req.rid])
+                          for e in entries]
+                arrays, smax, sq = model_exec.pack_prefill(
+                    chunks, self.pool.block_size)
+                arrays = [jnp.asarray(a) for a in arrays]
+            with tracing.span("exec.launch"):
+                logits, self.pool.kv = model_exec.prefill_packed(
+                    self.cfg, self.params, self.pool.kv, *arrays, smax, sq)
+                toks = jnp.argmax(logits, -1)
+            with tracing.span("exec.wait"):
+                nxt = np.asarray(toks)
         self.stats.packed_prefill_calls += 1
         self.stats.host_syncs += 1
-        nxt = np.asarray(jnp.argmax(logits, -1))
-        for i, e in enumerate(entries):
-            r = e.req
-            self.stats.prefill_tokens += e.n_tokens
-            if e.l_kv + e.n_tokens >= r.prompt_len and r.generated == 0:
-                self._finish_prefill(e, int(nxt[i]), emitted)
-            # recompute completion emits nothing (next decode pass does)
+        with tracing.span("engine.emit"):
+            for i, e in enumerate(entries):
+                r = e.req
+                self.stats.prefill_tokens += e.n_tokens
+                if e.l_kv + e.n_tokens >= r.prompt_len and r.generated == 0:
+                    self._finish_prefill(e, int(nxt[i]), emitted)
+                # recompute completion emits nothing (next decode pass does)
 
     def _run_prefill_fallback(self, entries: list, emitted: list) -> None:
         """Per-request chunked prefill (the pre-packed path, kept for
@@ -1018,22 +1044,33 @@ class Engine:
             r = e.req
             c = model_exec.bucket(e.n_tokens)
             ctx = e.l_kv
-            self._prepare_prefill(e)
-            toks = np.zeros((1, c), np.int32)
-            seq = self._seq_view(r)
-            toks[0, :e.n_tokens] = seq[ctx:ctx + e.n_tokens]
-            max_ctx = model_exec.bucket(ctx + c, buckets=(
-                self.max_ctx,)) if ctx + c <= self.max_ctx else ctx + c
-            maxp = max_ctx // self.pool.block_size
-            table = self.pool.table_array([r.rid], maxp=maxp)
-            logits, self.pool.kv = model_exec.prefill_chunk(
-                self.cfg, self.params, self.pool.kv, jnp.asarray(toks),
-                table, jnp.asarray([ctx], jnp.int32), max_ctx)
+            done = ctx + e.n_tokens >= r.prompt_len and r.generated == 0
+            with tracing.span("exec.prefill"):
+                with tracing.span("exec.stage"):
+                    self._prepare_prefill(e)
+                    toks = np.zeros((1, c), np.int32)
+                    seq = self._seq_view(r)
+                    toks[0, :e.n_tokens] = seq[ctx:ctx + e.n_tokens]
+                    max_ctx = model_exec.bucket(ctx + c, buckets=(
+                        self.max_ctx,)) if ctx + c <= self.max_ctx else ctx + c
+                    maxp = max_ctx // self.pool.block_size
+                    table = self.pool.table_array([r.rid], maxp=maxp)
+                    toks = jnp.asarray(toks)
+                    ctx_len = jnp.asarray([ctx], jnp.int32)
+                with tracing.span("exec.launch"):
+                    logits, self.pool.kv = model_exec.prefill_chunk(
+                        self.cfg, self.params, self.pool.kv, toks, table,
+                        ctx_len, max_ctx)
+                    if done:
+                        tok = jnp.argmax(logits[0, e.n_tokens - 1])
+                if done:
+                    with tracing.span("exec.wait"):
+                        tok = int(tok)
             self.stats.prefill_tokens += e.n_tokens
-            if ctx + e.n_tokens >= r.prompt_len and r.generated == 0:
+            if done:
                 self.stats.host_syncs += 1
-                tok = int(jnp.argmax(logits[0, e.n_tokens - 1]))
-                self._finish_prefill(e, tok, emitted)
+                with tracing.span("engine.emit"):
+                    self._finish_prefill(e, tok, emitted)
             # recompute completion emits nothing (next decode pass does)
 
     # ------------------------------------------------------------------
@@ -1227,26 +1264,34 @@ class EngineDriver:
             self._idle.set()
             self.sink(DriverFailed(self.iid, e))
 
+    def _admit(self) -> bool:
+        """Move everything in the inbox into the engine; True if anything
+        was there."""
+        eng, drained = self.engine, False
+        while True:
+            try:
+                item = self.inbox.get_nowait()
+            except queue.Empty:
+                return drained
+            if item[0] == "handoff":
+                payload = item[1]
+                if eng.import_handoff(payload):
+                    self.sink(HandoffAdopted(self.iid, payload))
+                else:
+                    self.sink(HandoffDropped(self.iid, payload))
+            else:
+                _, req, prompt, prior = item
+                eng.add_request(req, prompt, prior_outputs=prior)
+                self._awaiting_first.add(req.rid)
+            drained = True
+
     def _loop(self) -> None:
         eng = self.engine
         while not self._stop.is_set():
             drained = False
-            while True:
-                try:
-                    item = self.inbox.get_nowait()
-                except queue.Empty:
-                    break
-                if item[0] == "handoff":
-                    payload = item[1]
-                    if eng.import_handoff(payload):
-                        self.sink(HandoffAdopted(self.iid, payload))
-                    else:
-                        self.sink(HandoffDropped(self.iid, payload))
-                else:
-                    _, req, prompt, prior = item
-                    eng.add_request(req, prompt, prior_outputs=prior)
-                    self._awaiting_first.add(req.rid)
-                drained = True
+            if not self.inbox.empty():
+                with tracing.span("driver.admit"):
+                    drained = self._admit()
             res = eng.step() if eng.alive else None
             for payload in eng.take_handoffs():
                 payload.src_iid = self.iid
@@ -1264,9 +1309,6 @@ class EngineDriver:
                 iid=self.iid, free_blocks=eng.bm.free_blocks,
                 latency=res["latency"], est_time=res["plan"].est_time,
                 prefill_done=tuple(first_done),
-                finished=tuple(r.rid for r in res["finished"]),
-                offload_blocks=res.get("offload_blocks", 0),
-                reload_blocks=res.get("reload_blocks", 0),
-                transfer_wait=res.get("transfer_wait", 0.0)))
+                finished=tuple(r.rid for r in res["finished"])))
             if not eng.has_work() and self.inbox.empty():
                 self._idle.set()

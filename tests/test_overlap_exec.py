@@ -311,13 +311,6 @@ def test_refit_failures_logged_and_counted(monkeypatch):
     assert eng.est is before                # previous fit kept
 
 
-def test_batch_latencies_bounded():
-    eng = make_engine(overlap=False)
-    assert eng.stats.batch_latencies.maxlen == 512
-    for _ in range(600):
-        eng.stats.batch_latencies.append(0.01)
-    assert len(eng.stats.batch_latencies) == 512
-
 
 def test_seq_cache_tracks_prompt_and_outputs():
     eng = make_engine(overlap=False)
