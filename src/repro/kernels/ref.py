@@ -14,7 +14,8 @@ import jax.numpy as jnp
 
 def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, lengths):
     """q: (B, H, hd); pages: (P, Hkv, page, hd) head-major;
-    block_tables: (B, maxp); lengths: (B,).  Returns (B, H, hd)."""
+    block_tables: (B, maxp); lengths: (B,).  A row of length 0 attends to
+    nothing and returns zeros.  Returns (B, H, hd)."""
     b, h, hd = q.shape
     hkv, page = k_pages.shape[1], k_pages.shape[2]
     g = h // hkv
@@ -34,6 +35,7 @@ def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, lengths):
     s = jnp.where(mask[:, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgs,bskd->bkgd", p, v.astype(jnp.float32))
+    o = jnp.where(lengths[:, None, None, None] > 0, o, 0.0)
     return o.reshape(b, h, hd).astype(q.dtype)
 
 

@@ -835,23 +835,27 @@ class Engine:
         or the logits fallback)."""
         rids = [e.req.rid for e in decode_entries]
         nb = len(decode_entries)
-        with tracing.span("exec.decode"):
+        bs = self.pool.block_size
+        # pages each row's attention reads, and the table width once
+        # ensure_capacity below has grown each table to cover them
+        pages = [-(-(e.l_kv + 1) // bs) for e in decode_entries]
+        maxp = max(self.pool.table_len(e.req.rid, e.l_kv + 1)
+                   for e in decode_entries)
+        if self.fused_decode:
+            # pad batch/table to shape buckets (extra rows: token 0, len 0,
+            # null-block table) and fetch only the (B,) argmax
+            b_b = model_exec.seg_bucket(nb)
+            maxp = model_exec.table_bucket(maxp)
+        else:
+            b_b = nb
+        with tracing.span("exec.decode", valid_pages=sum(pages),
+                          table_pages=b_b * maxp):
             with tracing.span("exec.stage"):
                 for e in decode_entries:
                     self.pool.ensure_capacity(e.req.rid, e.l_kv + 1)
-                    if self.pool.ensure_writable(
-                            e.req.rid, e.l_kv // self.pool.block_size):
+                    if self.pool.ensure_writable(e.req.rid, e.l_kv // bs):
                         self.bm.note_fork(e.req)
                         self.stats.cow_forks += 1
-                maxp = max(len(self.pool.tables[r]) for r in rids)
-                if self.fused_decode:
-                    # pad batch/table to shape buckets (extra rows: token
-                    # 0, len 0, null-block table) and fetch only the (B,)
-                    # argmax
-                    b_b = model_exec.seg_bucket(nb)
-                    maxp = model_exec.table_bucket(maxp)
-                else:
-                    b_b = nb
                 lens = np.zeros(b_b, np.int32)
                 lens[:nb] = [e.l_kv for e in decode_entries]
                 last = np.zeros(b_b, np.int32)

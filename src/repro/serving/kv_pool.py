@@ -336,9 +336,14 @@ class PagedKVPool:
             t.append(b)
         return True
 
+    def table_len(self, rid: int, tokens: int) -> int:
+        """Length of rid's table once it covers ``tokens`` positions."""
+        return max(len(self.tables.get(rid, ())),
+                   -(-tokens // self.block_size))
+
     def ensure_capacity(self, rid: int, tokens: int) -> bool:
         """Grow rid's table to cover ``tokens`` positions."""
-        need = -(-tokens // self.block_size) - len(self.tables.get(rid, []))
+        need = self.table_len(rid, tokens) - len(self.tables.get(rid, ()))
         return self.alloc(rid, need) if need > 0 else True
 
     def release(self, rid: int) -> None:

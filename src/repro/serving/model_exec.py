@@ -116,8 +116,8 @@ def decode_step(cfg: ArchConfig, params, pool_kv, tokens, tables, lens):
     and an all-zero table row, so their single K/V write lands in the
     reserved null block 0 (the packed-prefill convention) and their output
     token is garbage the caller discards.  Real rows are unaffected — every
-    per-row computation is independent and the paged-attention kernel masks
-    table entries past ``lens``."""
+    per-row computation is independent and the paged-attention kernel
+    reads no table entry past ``lens``."""
     logits, pool_kv = _decode_forward(cfg, params, pool_kv, tokens, tables,
                                       lens)
     return jnp.argmax(logits, -1).astype(jnp.int32), pool_kv

@@ -8,6 +8,7 @@ import pytest
 from repro.kernels import (block_gather, chunked_prefill_attention,
                            kv_block_dequantize, kv_block_quantize,
                            paged_decode_attention)
+from repro.kernels.paged_attention import pages_per_block
 from repro.kernels.ref import (block_gather_ref,
                                chunked_prefill_attention_ref,
                                kv_block_dequantize_ref,
@@ -24,24 +25,74 @@ def tol(dtype):
         else dict(atol=2e-5, rtol=2e-5)
 
 
+def _decode_lens(case, b, page, maxp, span):
+    """Per-row lengths for a sweep case; ``span`` is the kernel's tokens
+    per grid step (pages per block times the page size)."""
+    rng = np.random.default_rng(0)
+    lens = rng.integers(1, maxp * page + 1, b)
+    if case == "len0":                      # every other row is empty
+        lens[::2] = 0
+    elif case == "block_edges":             # on a block's end, one past it
+        lens = np.resize([span, span + 1, span - 1, 1], b)
+    elif case == "nan_page_tail":           # odd: a last token's lane
+        lens = lens | 1                     # partner lies past the length
+    return jnp.asarray(np.minimum(lens, maxp * page), jnp.int32)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,h,hkv,hd,page,maxp", [
-    (2, 4, 4, 32, 16, 4),      # MHA (G=1)
-    (3, 8, 2, 64, 16, 5),      # GQA G=4
-    (1, 16, 2, 16, 8, 8),      # G=8, small pages
-    (4, 6, 6, 128, 32, 2),     # head_dim 128 (MXU-aligned)
+@pytest.mark.parametrize("b,h,hkv,hd,page,maxp,case", [
+    pytest.param(2, 4, 4, 32, 16, 4, "random",
+                 id="2-4-4-32-16-4"),                # MHA (G=1)
+    pytest.param(3, 8, 2, 64, 16, 5, "random",
+                 id="3-8-2-64-16-5"),                # GQA G=4
+    pytest.param(1, 16, 2, 16, 8, 8, "random",
+                 id="1-16-2-16-8-8"),                # G=8, small pages
+    pytest.param(4, 6, 6, 128, 32, 2, "random",
+                 id="4-6-6-128-32-2"),               # head_dim 128
+    pytest.param(5, 8, 2, 64, 16, 6, "len0", id="len0"),
+    # maxp 12 is not a multiple of its 8 pages per block
+    pytest.param(4, 8, 2, 64, 16, 12, "block_edges", id="block_edges"),
+    pytest.param(3, 8, 2, 64, 16, 7, "nan_tail", id="nan_tail"),
+    pytest.param(3, 8, 2, 64, 16, 7, "nan_page_tail", id="nan_page_tail"),
+    # Qwen1.5-0.5B's attention widths at a decode bucket's table width
+    pytest.param(3, 16, 16, 64, 16, 96, "random", id="mha_cell_widths"),
+    pytest.param(3, 28, 4, 128, 16, 6, "random",
+                 id="gqa_g7"),                       # Qwen2-7B's 28/4
 ])
-def test_paged_decode_attention_sweep(dtype, b, h, hkv, hd, page, maxp):
+def test_paged_decode_attention_sweep(dtype, b, h, hkv, hd, page, maxp,
+                                      case):
     ks = jax.random.split(KEY, 4)
     P = maxp * b + 3
     q = jax.random.normal(ks[0], (b, h, hd), dtype)
     kp = jax.random.normal(ks[1], (P, hkv, page, hd), dtype)
     vp = jax.random.normal(ks[2], (P, hkv, page, hd), dtype)
     bt = jax.random.randint(ks[3], (b, maxp), 0, P)
-    lens = jnp.asarray(
-        np.random.default_rng(0).integers(1, maxp * page + 1, b), jnp.int32)
-    out = paged_decode_attention(q, kp, vp, bt, lens)
+    span = page * pages_per_block(maxp, hkv, page, hd, dtype)
+    lens = _decode_lens(case, b, page, maxp, span)
+    if case == "nan_tail":
+        bt = bt % (P - 3)                     # the last 3 pages become NaN
+    if case == "nan_page_tail":               # a page of its own per entry
+        bt = jax.random.permutation(ks[3], P)[:b * maxp].reshape(b, maxp)
     ref = paged_decode_attention_ref(q, kp, vp, bt, lens)
+    if case == "nan_tail":
+        # table entries past each row's last page name pages of NaN: the
+        # kernel must neither read them nor let a stale buffer reach the
+        # output, which must equal the reference over finite pages
+        nan_pages = jnp.arange(P - 3, P)
+        kp = kp.at[nan_pages].set(jnp.nan)
+        vp = vp.at[nan_pages].set(jnp.nan)
+        past = jnp.arange(maxp)[None, :] * page >= lens[:, None]
+        bt = jnp.where(past, nan_pages[jnp.arange(maxp) % 3][None, :], bt)
+        assert bool(past.any())
+    if case == "nan_page_tail":
+        # the unwritten tail of each row's last page is NaN: a valid
+        # token's score must not take in its lane partner past the length
+        last = bt[jnp.arange(b), (lens - 1) // page]
+        tail = jnp.arange(page)[None, :] >= (lens % page)[:, None]
+        mask = jnp.zeros((P, page), bool).at[last].set(tail)
+        kp = jnp.where(mask[:, None, :, None], jnp.nan, kp)
+        vp = jnp.where(mask[:, None, :, None], jnp.nan, vp)
+    out = paged_decode_attention(q, kp, vp, bt, lens)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **tol(dtype))
 

@@ -17,6 +17,7 @@ import pytest
 from repro.configs import get_smoke
 from repro.core import EngineConfig, Request, SLO, make_policy
 from repro.kernels import packed_verify_attention, paged_decode_attention
+from repro.kernels.paged_attention import pages_per_block
 from repro.kernels.ref import packed_verify_attention_ref
 from repro.models import init_params
 from repro.serving import Engine
@@ -144,10 +145,12 @@ def test_spec_requires_draft(model):
 def test_packed_verify_kernel_contract():
     """Row-for-row the packed kernel must be BITWISE equal to the plain
     paged-decode kernel run with gathered per-row tables (same body, same
-    accumulation order) and allclose to the naive softmax oracle."""
+    accumulation order) and allclose to the naive softmax oracle; with a
+    row that spans more than one of the kernel's page blocks, and a row
+    of length 0 (which attends to nothing and returns zeros)."""
     key = jax.random.PRNGKey(3)
     page, hkv, g, hd = 8, 2, 4, 16
-    n_pages, maxp, n_seg = 24, 3, 3
+    n_pages, maxp, n_seg = 24, 6, 3
     depth = 2
     k1, k2, k3 = jax.random.split(key, 3)
     k_pages = jax.random.normal(k1, (n_pages, hkv, page, hd), jax.numpy.float32)
@@ -155,11 +158,16 @@ def test_packed_verify_kernel_contract():
     rng = np.random.default_rng(5)
     tables = rng.permutation(np.arange(1, n_pages))[:n_seg * maxp]
     tables = tables.reshape(n_seg, maxp).astype(np.int32)
-    # rows: (seg, j) for j = 0..depth; per-row length l_kv + j + 1
-    base = np.array([9, 14, 20], np.int32)
-    row_seg = np.repeat(np.arange(n_seg, dtype=np.int32), depth + 1)
+    # rows: (seg, j) for j = 0..depth; per-row length l_kv + j + 1; then
+    # one row of length 0
+    base = np.array([9, 14, 40], np.int32)
+    row_seg = np.append(np.repeat(np.arange(n_seg, dtype=np.int32),
+                                  depth + 1), np.int32(1))
     lengths = np.concatenate(
-        [b + np.arange(depth + 1, dtype=np.int32) + 1 for b in base])
+        [b + np.arange(depth + 1, dtype=np.int32) + 1 for b in base]
+        + [np.zeros(1, np.int32)])
+    span = page * pages_per_block(maxp, hkv, page, hd, jax.numpy.float32)
+    assert lengths.max() > span
     q = jax.random.normal(k3, (len(row_seg), hkv * g, hd), jax.numpy.float32)
 
     out = packed_verify_attention(q, k_pages, v_pages,
@@ -176,3 +184,4 @@ def test_packed_verify_kernel_contract():
                                       jax.numpy.asarray(row_seg))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+    assert not np.asarray(out[-1]).any()

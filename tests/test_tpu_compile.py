@@ -11,6 +11,7 @@ one process at a time may load the TPU library, so a worker that loads it
 while collecting would make the others fail to collect.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +80,24 @@ def test_paged_decode_compiles(one_chip, dtype, bs):
              ((POOL_BLOCKS, KV_HEADS, bs, HD), dtype),
              ((POOL_BLOCKS, KV_HEADS, bs, HD), dtype),
              ((b, maxp), jnp.int32), ((b,), jnp.int32))
+
+
+@pytest.mark.parametrize("maxp", [192, 256])
+def test_paged_decode_compiles_at_cell_buckets(one_chip, maxp):
+    """The benchmark cell's largest decode buckets (24 rows, float32 KV in
+    16-token pages, a 1,024-page pool) fit the kernel's VMEM, and the
+    custom call keeps the name the benchmark's roofline reader matches."""
+    b = 24
+    compiled = _compile(
+        lambda q, k, v, t, n: paged_decode_attention(q, k, v, t, n),
+        one_chip,
+        ((b, HEADS, HD), jnp.float32),
+        ((1024, KV_HEADS, 16, HD), jnp.float32),
+        ((1024, KV_HEADS, 16, HD), jnp.float32),
+        ((b, maxp), jnp.int32), ((b,), jnp.int32))
+    assert re.search(r"%paged_decode_attention(\.\d+)? = .*"
+                     r'custom_call_target="tpu_custom_call"',
+                     compiled.as_text())
 
 
 @pytest.mark.parametrize("bs", BLOCK_SIZES)

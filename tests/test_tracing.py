@@ -289,6 +289,23 @@ def test_collect_reads_a_traced_engine_run(params, tmp_path):
         att["steps_with_work"])
 
 
+def test_decode_spans_count_the_pages_read(params, tmp_path):
+    """``exec.decode`` carries the pages its rows' attention reads and the
+    pages of the bucketed table the launch is given."""
+    eng = _engine(params, False)
+    _submit(eng, np.random.default_rng(3))
+    with traced(tmp_path) as raw:
+        eng.run_until_drained()
+    spans = [s for s, _ in named(raw, "exec.decode")]
+    assert len(spans) == eng.stats.decode_launches > 0
+    valid = [int(s[3]["valid_pages"]) for s in spans]
+    table = [int(s[3]["table_pages"]) for s in spans]
+    assert all(0 < v <= t for v, t in zip(valid, table))
+    # every decode row (one token each; prefill emits each request's
+    # first) has 21 to 24 tokens of context: two 16-token pages
+    assert sum(valid) == 2 * (eng.stats.tokens_out - 3)
+
+
 def test_a_failed_step_leaves_no_span_open(params, tmp_path):
     eng = _engine(params, True)
     _submit(eng, np.random.default_rng(1), n=1)
