@@ -3,9 +3,15 @@
 These count what the computation requires, not what the compiled program
 does: valid context only (no bucket padding, no null pages), each K/V byte
 read once per layer, float32 operands (the program serves float32 weights
-and KV).  A multiply-add is two operations.
+and KV).  A multiply-add is two operations.  Attention (projections and
+scores) and the output head are counted here for every family; the
+feed-forward's count per token is the family's
+(``families/<model_type>.py`` ``ffn_flops_per_token``), taken once by
+``arch``.
 """
 from __future__ import annotations
+
+from . import families
 
 F32 = 4
 
@@ -14,14 +20,15 @@ def arch(cfg: dict) -> dict:
     d = cfg["hidden_size"]
     h, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     return {"d": d, "h": h, "kv": kv, "hd": cfg.get("head_dim", d // h),
-            "ff": cfg["intermediate_size"], "v": cfg["vocab_size"],
-            "layers": cfg["num_hidden_layers"]}
+            "ffn": families.load(cfg).ffn_flops_per_token(cfg),
+            "v": cfg["vocab_size"], "layers": cfg["num_hidden_layers"]}
 
 
-def dense_flops_per_token(a: dict) -> float:
-    """Projections and MLP of every layer, for one token."""
+def token_flops(a: dict) -> float:
+    """Attention projections and feed-forward of every layer, for one
+    token."""
     proj = a["d"] * (a["h"] + 2 * a["kv"]) * a["hd"] + a["h"] * a["hd"] * a["d"]
-    return 2.0 * a["layers"] * (proj + 3 * a["d"] * a["ff"])
+    return 2.0 * a["layers"] * proj + a["ffn"]
 
 
 def head_flops(a: dict) -> float:
@@ -67,7 +74,7 @@ def step_flops(a: dict, decode_lens, prefill_segs) -> float:
     keys = (sum(l + 1 for l in decode_lens)
             + sum(prefill_keys(c, n) for c, n in prefill_segs))
     heads = len(decode_lens) + len(prefill_segs)
-    return (n_tok * dense_flops_per_token(a) + attn_flops(a, keys)
+    return (n_tok * token_flops(a) + attn_flops(a, keys)
             + heads * head_flops(a))
 
 

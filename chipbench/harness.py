@@ -32,7 +32,7 @@ from pathlib import Path
 
 import numpy as np
 
-from . import e2e, judge, traffic
+from . import e2e, families, judge, traffic
 from .spans import Recorder, instrument
 from .spec import ROOT, Cell
 
@@ -95,19 +95,9 @@ def require_chip(jax, chips: int) -> None:
 
 
 def arch_config(cfg: dict):
-    """The program's ``ArchConfig`` for a configuration file."""
-    from repro.models.model import ArchConfig
-    if cfg.get("hidden_act") != "silu" or float(cfg["rms_norm_eps"]) != 1e-6:
-        raise ValueError("the engine serves SiLU MLPs with RMSNorm eps 1e-6 "
-                         "only")
-    h = cfg["num_attention_heads"]
-    return ArchConfig(
-        name=cfg["name"], family="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=h, n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        head_dim=cfg.get("head_dim", cfg["hidden_size"] // h),
-        qkv_bias=True, rope_theta=float(cfg["rope_theta"]))
+    """The program's ``ArchConfig`` for a configuration file, from its
+    family (``families/<model_type>.py``)."""
+    return families.load(cfg).arch_config(cfg)
 
 
 @dataclass

@@ -1,13 +1,16 @@
 """Plain reference of the served model, and its lower-precision control.
 
-A straightforward Qwen2 decoder in ``jax.numpy``, written from the published
-architecture and independent of the program: RMSNorm (eps from the
-configuration), Q/K/V projections with bias, rotary embeddings at the
-configuration's ``rope_theta``, causal grouped-query attention, SwiGLU MLP,
-final norm and output head.  One departure from the Hugging Face code, shared
-with the program: rotary pairs are adjacent dimensions (2i, 2i+1), as in the
-RoFormer paper, not the two halves of a head; the two conventions are the
-same model under a fixed permutation of the q/k projection columns.
+A straightforward decoder in ``jax.numpy``, written from the published
+architecture and independent of the program.  Shared here by every family:
+RMSNorm (eps from the configuration), Q/K/V projections with bias, rotary
+embeddings at the configuration's ``rope_theta``, causal grouped-query
+attention, the residual stream, final norm and output head.  The
+feed-forward of each layer is the family's (``families/<model_type>.py``
+``ffn``; Qwen2's is a SwiGLU MLP).  One departure from the Hugging Face
+code, shared with the program: rotary pairs are adjacent dimensions
+(2i, 2i+1), as in the RoFormer paper, not the two halves of a head; the two
+conventions are the same model under a fixed permutation of the q/k
+projection columns.
 
 ``readings`` runs the reference in float32, with its matmuls at the
 precision the configuration states for the served model
@@ -19,8 +22,8 @@ the same code in bfloat16 (weights, activations and KV; norms and softmax
 in float32 as bf16 serving computes them), the nearest precision below the
 stated one; the harness's check must fail it.
 
-Both run layer by layer inside one scan, with queries and MLP rows in
-blocks and the output head over blocks of rows, so they fit beside the
+Both run layer by layer inside one scan, with queries and feed-forward rows
+in blocks and the output head over blocks of rows, so they fit beside the
 weights once the engine's state is freed.
 """
 from __future__ import annotations
@@ -31,6 +34,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from . import families
 
 SEQ_STEP = 1024          # sequences are padded to a multiple of this
 Q_BLOCK = 512            # query rows per attention block
@@ -64,7 +69,8 @@ def hidden(params, tokens, arch: tuple, dtype_name: str, precision: str):
     """Final-norm hidden states (S, d) of a padded sequence.  Padding sits
     after the real tokens, so causal attention keeps it out of every real
     position."""
-    n_heads, n_kv, hd, theta, eps = arch
+    n_heads, n_kv, hd, theta, eps, model_type = arch
+    ffn = families.load({"model_type": model_type}).ffn
     dt = jnp.dtype(dtype_name)
     mm = functools.partial(jnp.einsum, precision=PRECISION[precision])
     s = tokens.shape[0]
@@ -94,15 +100,9 @@ def hidden(params, tokens, arch: tuple, dtype_name: str, precision: str):
         o = jax.lax.map(attend, (jnp.arange(s // Q_BLOCK), qb))
         o = o.reshape(s, n_heads * hd)
         x = x + mm("sk,kd->sd", o, a["wo"])
-        m = lp["mlp"]
         h2 = _rmsnorm(x, lp["ln2"]["scale"], eps)
-
-        def mlp(rows):
-            gate = mm("sd,df->sf", rows, m["w_gate"])
-            up = mm("sd,df->sf", rows, m["w_up"])
-            return mm("sf,fd->sd", jax.nn.silu(gate) * up, m["w_down"])
-
-        y = jax.lax.map(mlp, h2.reshape(s // SEQ_STEP, SEQ_STEP, -1))
+        y = jax.lax.map(lambda rows: ffn(lp, rows, mm),
+                        h2.reshape(s // SEQ_STEP, SEQ_STEP, -1))
         return x + y.reshape(x.shape), None
 
     x, _ = jax.lax.scan(layer, x, params["layers"])
@@ -127,7 +127,8 @@ def arch_of(cfg: dict) -> tuple:
     h = cfg["num_attention_heads"]
     return (h, cfg["num_key_value_heads"],
             cfg.get("head_dim", cfg["hidden_size"] // h),
-            float(cfg["rope_theta"]), float(cfg["rms_norm_eps"]))
+            float(cfg["rope_theta"]), float(cfg["rms_norm_eps"]),
+            cfg["model_type"])
 
 
 def _rows(params, cfg, prompt, served, cand, dtype_name, precision):

@@ -1,7 +1,7 @@
 """Find a cell's configuration, traffic mix and metric readers by name.
 
-A later change adds a configuration, a mix or a per-layer metric by adding
-a file; nothing here names one.
+A later change adds a configuration, a mix, a per-layer metric or a model
+family (families/) by adding a file; nothing here names one.
 """
 from __future__ import annotations
 
@@ -9,6 +9,8 @@ import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
+
+from . import families
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -54,6 +56,7 @@ def load_cell(name: str, bench_path: Path = ROOT / "BENCHMARK.json") -> Cell:
     w = cells[name]
     cfg_entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
     config = load_json(ROOT / cfg_entry["file"])
+    families.load(config)
     return Cell(
         name=name, chips=int(w["chips"]), config=config,
         traffic=load_traffic(w["traffic"]),

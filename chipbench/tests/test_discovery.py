@@ -3,7 +3,7 @@ import json
 
 import pytest
 
-from chipbench import spec
+from chipbench import families, spec
 
 BENCH = json.loads((spec.ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -45,6 +45,7 @@ def test_configs_hold_what_is_run():
         assert cfg["reduced"] == c["reduced"]
         assert {"num_blocks", "block_size", "max_ctx"} <= set(cfg["serve"])
         assert set(cfg["check"]["limits"]) == {"gap_mean"}
+        assert (families.HERE / f"{cfg['model_type']}.py").exists()
 
 
 def test_unknown_names_are_errors(tmp_path):

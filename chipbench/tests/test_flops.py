@@ -4,8 +4,9 @@ import pytest
 from chipbench import flops
 from chipbench.peaks import peaks_for
 
-A = {"hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
-     "intermediate_size": 128, "vocab_size": 100, "num_hidden_layers": 3}
+A = {"model_type": "qwen2", "hidden_size": 64, "num_attention_heads": 4,
+     "num_key_value_heads": 2, "intermediate_size": 128, "vocab_size": 100,
+     "num_hidden_layers": 3}
 
 
 def test_arch_and_dense():
@@ -13,7 +14,7 @@ def test_arch_and_dense():
     assert a["hd"] == 16
     # q 64x64, k/v 64x32 each, o 64x64, mlp 3 x 64x128 -> per layer
     per_layer = 64 * 64 + 2 * 64 * 32 + 64 * 64 + 3 * 64 * 128
-    assert flops.dense_flops_per_token(a) == 2 * 3 * per_layer
+    assert flops.token_flops(a) == 2 * 3 * per_layer
     assert flops.head_flops(a) == 2 * 100 * 64
 
 
@@ -44,7 +45,7 @@ def test_prefill_attention_cost():
 def test_step_flops_adds_up():
     a = flops.arch(A)
     got = flops.step_flops(a, [9], [(0, 4)])
-    want = (5 * flops.dense_flops_per_token(a)
+    want = (5 * flops.token_flops(a)
             + flops.attn_flops(a, 10 + 10) + 2 * flops.head_flops(a))
     assert got == want
 

@@ -1,11 +1,14 @@
 """Random weights from the seed, made on the device in one jitted call.
 
-The layout is the one the serving engine takes (stacked layers, see
+The shape tree is the family's (``families/<model_type>.py`` ``shapes``),
+in the layout the serving engine takes (stacked layers, see
 ``repro.models.model.init_params``); the benchmark makes the weights itself
-so that its reference uses nothing the program made.  Every matrix and bias
-is normal with the configuration's ``initializer_range``; norm scales are 1.
-A configuration with ``tie_word_embeddings`` gets one matrix as both the
-embedding and the output head (the same device buffer under two keys).
+so that its reference uses nothing the program made.  What is shared here
+is how they are drawn: every matrix and bias is normal with the
+configuration's ``initializer_range``, one key per leaf in the tree's
+order; norm scales are 1.  A configuration with ``tie_word_embeddings``
+gets one matrix as both the embedding and the output head (the same device
+buffer under two keys).
 """
 from __future__ import annotations
 
@@ -15,24 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import families
 from .traffic import seed_words
-
-
-def shapes(cfg: dict) -> dict:
-    d = cfg["hidden_size"]
-    h, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = cfg.get("head_dim", d // h)
-    f, v, n = cfg["intermediate_size"], cfg["vocab_size"], cfg["num_hidden_layers"]
-    return {
-        "embed": (v, d), "lm_head": (v, d), "ln_f": {"scale": (d,)},
-        "layers": {
-            "ln1": {"scale": (n, d)}, "ln2": {"scale": (n, d)},
-            "attn": {"wq": (n, d, h * hd), "wk": (n, d, kv * hd),
-                     "wv": (n, d, kv * hd), "wo": (n, h * hd, d),
-                     "bq": (n, h * hd), "bk": (n, kv * hd),
-                     "bv": (n, kv * hd)},
-            "mlp": {"w_gate": (n, d, f), "w_up": (n, d, f),
-                    "w_down": (n, f, d)}}}
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
@@ -69,7 +56,7 @@ def _nest(flat: dict) -> dict:
 
 def make_params(cfg: dict, seed: int) -> dict:
     tied = bool(cfg.get("tie_word_embeddings", False))
-    items = tuple((p, s) for p, s in _flatten(shapes(cfg))
+    items = tuple((p, s) for p, s in _flatten(families.load(cfg).shapes(cfg))
                   if not (tied and p == "lm_head"))
     key = jax.random.wrap_key_data(np.asarray(seed_words(seed), np.uint32))
     flat = _make(key, items, float(cfg["initializer_range"]))
