@@ -8,9 +8,12 @@ module provides exactly four things:
 * ``arch_config(cfg)``: the program's ``ArchConfig`` for the file;
 * ``shapes(cfg)``: the parameter shape tree (``weights.make_params`` draws
   its leaves in this tree's order);
-* ``ffn(lp, rows, mm)``: the reference's feed-forward of one layer on a
-  block of rows, with the layer's parameters ``lp`` and ``rows`` in the
-  dtype to compute in and ``mm`` the einsum at the matmul precision;
+* ``ffn(lp, rows, mm, cfg)``: the reference's feed-forward of one layer
+  on a block of rows, with the layer's parameters ``lp`` and ``rows`` in
+  the dtype to compute in, ``mm`` the einsum at the matmul precision and
+  ``cfg`` the configuration dict, from which a family reads what its
+  weights' shapes do not say (experts per token, how the gate is
+  normalised); a family that needs none of it ignores it;
 * ``ffn_flops_per_token(cfg)``: the feed-forward's operations for one
   token through every layer (a multiply-add is two).
 

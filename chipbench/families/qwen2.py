@@ -37,7 +37,8 @@ def shapes(cfg: dict) -> dict:
                     "w_down": (n, f, d)}}}
 
 
-def ffn(lp, rows, mm):
+def ffn(lp, rows, mm, cfg):
+    """SwiGLU; its widths are the weights', so ``cfg`` is not read."""
     m = lp["mlp"]
     gate = mm("sd,df->sf", rows, m["w_gate"])
     up = mm("sd,df->sf", rows, m["w_up"])

@@ -22,6 +22,8 @@ Timeline of a run (all on ``time.monotonic()``):
 from __future__ import annotations
 
 import asyncio
+import copy
+import dataclasses
 import gc
 import os
 import sys
@@ -129,11 +131,26 @@ def _queue(sent) -> tuple:
 
 
 def _stats(engine) -> dict:
+    """A copy of every field of the engine's ``EngineStats``, so that a
+    counter the program adds reaches the readers without a change here."""
     s = engine.stats
-    return {k: getattr(s, k) for k in (
-        "iterations", "tokens_out", "prefill_tokens", "evictions",
-        "reload_blocks", "decode_launches", "packed_prefill_calls",
-        "host_syncs", "cache_hit_tokens")}
+    return {f.name: copy.deepcopy(getattr(s, f.name))
+            for f in dataclasses.fields(s)}
+
+
+def _changed(a: dict, b: dict) -> str:
+    """``name=value`` of each counter that moved between two ``_stats``
+    snapshots: the difference of a count, ``old->new`` of anything
+    else."""
+    out = []
+    for k, v in b.items():
+        if v == a.get(k):
+            continue
+        if type(v) is int and type(a.get(k)) is int:
+            out.append(f"{k}={v - a[k]}")
+        else:
+            out.append(f"{k}={a.get(k)!r}->{v!r}")
+    return " ".join(out)
 
 
 async def _consume(stream, sent: e2e.Sent) -> None:
@@ -149,6 +166,7 @@ async def _serve(cell: Cell, params, arch, reqs, warm, seconds: float,
                  trace: bool, t0: float, counter: CompileCounter,
                  trace_dir: str) -> Outcome:
     import jax
+    from repro import tracing
     from repro.core import (SLO, EngineConfig, GoRouting, Request,
                             RouterConfig, make_policy)
     from repro.core.estimator import BatchLatencyEstimator
@@ -234,6 +252,7 @@ async def _serve(cell: Cell, params, arch, reqs, warm, seconds: float,
         while True:
             now = time.monotonic()
             if trace and not traced and now >= t_open - 3.0:
+                tracing.enable()           # the program's own spans too
                 opts = jax.profiler.ProfileOptions()
                 opts.host_tracer_level = 1       # TraceMe spans, no more
                 opts.python_tracer_level = 0
@@ -285,6 +304,7 @@ async def _serve(cell: Cell, params, arch, reqs, warm, seconds: float,
             await asyncio.sleep(0.25)
         out.drain_s = time.monotonic() - t_drain
     finally:
+        tracing.disable()
         await fe.stop()
         for d in fe.drivers.values():
             d._thread.join(timeout=600.0)
@@ -396,8 +416,7 @@ def _result(cell, out: Outcome, params, seed, trace, trace_dir, dev) -> dict:
         f"{out.queue[-1]}; offered {len(window) / (t_close - t_open):.3f} "
         f"req/s, rate {mix['rate_rps']}")
     for i, (a, b) in enumerate(out.stats):
-        log(f"replica {i} in window: " + " ".join(
-            f"{k}={b[k] - a[k]}" for k in a))
+        log(f"replica {i} in window: {_changed(a, b)}")
         log(f"replica {i} estimator at open {out.est[i][0]}, at close "
             f"{out.est[i][1]}")
     plans = [p for p in out.rec.plans if t_open <= p[0] < t_close]
@@ -439,7 +458,7 @@ def _result(cell, out: Outcome, params, seed, trace, trace_dir, dev) -> dict:
                   peak=peaks_for(dev.device_kind) if dev.platform == "tpu"
                   else None)
     if trace:
-        from . import trace_reduce
+        from . import program_trace, trace_reduce
         t_tr = time.monotonic()
         paths = sorted(Path(trace_dir).rglob("*.xplane.pb"))
         ctx.red = trace_reduce.reduce(trace_reduce.load(str(paths[-1])),
@@ -450,6 +469,18 @@ def _result(cell, out: Outcome, params, seed, trace, trace_dir, dev) -> dict:
         top = sorted(ctx.red["ops"].items(), key=lambda kv: -kv[1][1])
         log(f"trace reduced in {time.monotonic() - t_tr:.3f} s: "
             f"modules {ctx.red['modules']}; top ops {top[:15]}")
+        t_tr = time.monotonic()
+        ctx.program = program_trace.collect(str(paths[-1]))
+        if ctx.program is None:
+            log("program spans: none in the window")
+        else:
+            log(f"program spans read in {time.monotonic() - t_tr:.3f} s: "
+                f"{len(ctx.program['spans'])} in the window; idle by "
+                f"innermost span "
+                f"{program_trace.table(ctx.program['attribution'])}; "
+                f"clock {ctx.program['clock']}")
+            log(f"programs traced, lowered, compiled or loaded in the "
+                f"window: {ctx.program['programs']}")
     for m in (cell.per_layer if trace else cell.end_to_end):
         v = load_reader(m["name"]).read(ctx)
         if v is not None:

@@ -13,7 +13,9 @@ idle interval of the window by the innermost span open over each part of
 it: on the engine's driver thread first (a thread whose line holds
 ``ps:engine.step``), then on the other threads, and ``none`` for what no
 span covers.  On the same spans it reads the seconds of compile stages in
-the window and the host path of each step.  Where the trace holds no
+the window and the host path of each step, and it hands on every program
+span in the window with its attributes, so that a reader of a span that a
+later program adds needs no change here.  Where the trace holds no
 program span (the program's tracer was off or absent) there is nothing
 to read, and ``collect`` returns None.
 """
@@ -121,9 +123,11 @@ def split(idle, pieces):
     return got, left
 
 
-def _in_window(raw, spans, name):
+def _in_window(raw, spans, name=None):
+    """The spans (of ``name``, or all) whose midpoint is in the window."""
     lo, hi = raw["window"]
-    return [s for s in spans if s[0] == name and lo <= (s[1] + s[2]) / 2 < hi]
+    return [s for s in spans if (name is None or s[0] == name)
+            and lo <= (s[1] + s[2]) / 2 < hi]
 
 
 def _drivers(raw) -> list:
@@ -257,16 +261,26 @@ def programs(raw: dict) -> list:
                   key=lambda r: -r[3])
 
 
-def collect(xplane: str) -> dict | None:
-    """What the readers need from a traced run's xplane; None where it
-    holds no window or no program span."""
-    raw = load(xplane)
+def summarize(raw: dict) -> dict | None:
+    """What the readers need from ``load``'s lists: the attribution, the
+    clock check, ``compile_s``, ``step_host_ms``, ``programs``, and
+    ``spans``, every program span of every thread whose midpoint is in the
+    window, ``(name, t0, t1, attrs)`` in ns on the trace's clock, in order
+    of start; None where the trace holds no window or no program span."""
     att = attribute(raw)
     if att is None:
         return None
     return {"attribution": att, "clock": clock_check(raw),
             "compile_s": compile_s(raw), "step_host_ms": step_host_ms(raw),
-            "programs": programs(raw)}
+            "programs": programs(raw),
+            "spans": sorted((s for ln in raw["lines"]
+                             for s in _in_window(raw, ln)),
+                            key=lambda s: (s[1], -s[2]))}
+
+
+def collect(xplane: str) -> dict | None:
+    """``summarize`` of a traced run's xplane."""
+    return summarize(load(xplane))
 
 
 def table(att: dict, top: int = 10) -> list:
@@ -281,8 +295,8 @@ def main(argv) -> int:
         print("trace holds no cb:window span or no program span",
               file=sys.stderr)
         return 1
-    print(json.dumps(dict(prog, table=table(prog["attribution"])),
-                     indent=1))
+    print(json.dumps(dict(prog, spans=len(prog["spans"]),
+                          table=table(prog["attribution"])), indent=1))
     return 0
 
 

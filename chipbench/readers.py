@@ -2,10 +2,13 @@
 
 Each metric is a file ``metrics/<name>.py`` whose ``read(ctx)`` returns the
 metric's value, or None where the run holds nothing to read.  ``ctx`` is a
-``Context``: the run's outcome (client stamps, harness spans, engine
-counters), the cell's configuration and mix, and, in a traced run, the
-trace reduction.  A share of a roofline or a peak is never made up as 0:
-with no device time to divide by, the reader returns None.
+``Context``: the run's outcome (client stamps, harness spans, and every
+field of each engine's ``EngineStats`` at the window's open and close), the
+cell's configuration and mix, and, in a traced run, the trace reduction
+and the program's own spans (``program``, from program_trace.collect: its
+``spans`` hold each program span in the window with its attributes, for a
+reader to pick by name).  A share of a roofline or a peak is never made up
+as 0: with no device time to divide by, the reader returns None.
 """
 from __future__ import annotations
 
@@ -25,6 +28,7 @@ class Context:
     chips: int
     peak: dict | None = None
     red: dict | None = None    # trace_reduce.reduce(...) of a traced run
+    program: dict | None = None    # program_trace.collect(...) likewise
 
     @property
     def window(self):

@@ -6,7 +6,9 @@ RMSNorm (eps from the configuration), Q/K/V projections with bias, rotary
 embeddings at the configuration's ``rope_theta``, causal grouped-query
 attention, the residual stream, final norm and output head.  The
 feed-forward of each layer is the family's (``families/<model_type>.py``
-``ffn``; Qwen2's is a SwiGLU MLP).  One departure from the Hugging Face
+``ffn``; Qwen2's is a SwiGLU MLP), which is handed the whole
+configuration, so that a family reads its own keys (experts per token, a
+gate's normalisation) from the file.  One departure from the Hugging Face
 code, shared with the program: rotary pairs are adjacent dimensions
 (2i, 2i+1), as in the RoFormer paper, not the two halves of a head; the two
 conventions are the same model under a fixed permutation of the q/k
@@ -29,6 +31,7 @@ weights once the engine's state is freed.
 from __future__ import annotations
 
 import functools
+import json
 import math
 
 import jax
@@ -65,12 +68,17 @@ PRECISION = {"default": jax.lax.Precision.DEFAULT,
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4))
-def hidden(params, tokens, arch: tuple, dtype_name: str, precision: str):
+def hidden(params, tokens, cfg_json: str, dtype_name: str, precision: str):
     """Final-norm hidden states (S, d) of a padded sequence.  Padding sits
     after the real tokens, so causal attention keeps it out of every real
-    position."""
-    n_heads, n_kv, hd, theta, eps, model_type = arch
-    ffn = families.load({"model_type": model_type}).ffn
+    position.  ``cfg_json`` is the configuration as ``static_config``
+    writes it, so this compiles once per configuration, dtype and
+    precision."""
+    cfg = json.loads(cfg_json)
+    ffn = families.load(cfg).ffn
+    n_heads, n_kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    hd = cfg.get("head_dim", cfg["hidden_size"] // n_heads)
+    theta, eps = float(cfg["rope_theta"]), float(cfg["rms_norm_eps"])
     dt = jnp.dtype(dtype_name)
     mm = functools.partial(jnp.einsum, precision=PRECISION[precision])
     s = tokens.shape[0]
@@ -101,7 +109,7 @@ def hidden(params, tokens, arch: tuple, dtype_name: str, precision: str):
         o = o.reshape(s, n_heads * hd)
         x = x + mm("sk,kd->sd", o, a["wo"])
         h2 = _rmsnorm(x, lp["ln2"]["scale"], eps)
-        y = jax.lax.map(lambda rows: ffn(lp, rows, mm),
+        y = jax.lax.map(lambda rows: ffn(lp, rows, mm, cfg),
                         h2.reshape(s // SEQ_STEP, SEQ_STEP, -1))
         return x + y.reshape(x.shape), None
 
@@ -123,12 +131,10 @@ def _head_rows(lm_head, h_rows, cand, valid, dtype_name: str, precision: str):
             jnp.where(valid[None], vals, 0.0))
 
 
-def arch_of(cfg: dict) -> tuple:
-    h = cfg["num_attention_heads"]
-    return (h, cfg["num_key_value_heads"],
-            cfg.get("head_dim", cfg["hidden_size"] // h),
-            float(cfg["rope_theta"]), float(cfg["rms_norm_eps"]),
-            cfg["model_type"])
+def static_config(cfg: dict) -> str:
+    """The configuration as ``hidden``'s static argument: its JSON with
+    sorted keys, hashable and equal for equal configurations."""
+    return json.dumps(cfg, sort_keys=True)
 
 
 def _rows(params, cfg, prompt, served, cand, dtype_name, precision):
@@ -139,7 +145,8 @@ def _rows(params, cfg, prompt, served, cand, dtype_name, precision):
     s_pad = -(-len(seq) // SEQ_STEP) * SEQ_STEP
     toks = np.zeros(s_pad, np.int32)
     toks[:len(seq)] = seq
-    h = hidden(params, jnp.asarray(toks), arch_of(cfg), dtype_name, precision)
+    h = hidden(params, jnp.asarray(toks), static_config(cfg), dtype_name,
+               precision)
     best, arg, vals = [], [], []
     for r0 in range(0, n, ROW_BLOCK):
         idx = np.arange(r0, r0 + ROW_BLOCK)

@@ -73,8 +73,8 @@ def record() -> dict:
     params = make_params(cfg, SEED)
     prompt, served = request(cfg["vocab_size"])
     toks = jnp.asarray(padded(prompt, served, reference.SEQ_STEP))
-    arch = reference.arch_of(cfg)
-    hid = {dt: np.asarray(reference.hidden(params, toks, arch, dt, prec))
+    frozen = reference.static_config(cfg)
+    hid = {dt: np.asarray(reference.hidden(params, toks, frozen, dt, prec))
            for dt, prec in (("float32", cfg["serve"]["matmul_precision"]),
                             ("bfloat16", "default"))}
     ctrl = reference.control_tokens(params, cfg, prompt, served)

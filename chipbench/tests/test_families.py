@@ -62,7 +62,7 @@ def shapes(cfg):
             "ffn": {"w_in": (n, d, f), "w_out": (n, f, d)}}}
 
 
-def ffn(lp, rows, mm):
+def ffn(lp, rows, mm, cfg):
     f = lp["ffn"]
     return mm("sf,fd->sd", jax.nn.gelu(mm("sd,df->sf", rows, f["w_in"])),
               f["w_out"])
@@ -116,13 +116,136 @@ def test_a_family_file_supplies_all_four(gelu_family):
 
     # the family's own weights reach the hidden states through its ffn
     toks = record_golden.padded(prompt, served, reference.SEQ_STEP)
-    h = reference.hidden(params, toks, reference.arch_of(cfg), "float32",
+    h = reference.hidden(params, toks, reference.static_config(cfg), "float32",
                          "highest")
     no_ffn = dict(params, layers=dict(layers, ffn=dict(
         layers["ffn"], w_out=0 * layers["ffn"]["w_out"])))
-    h0 = reference.hidden(no_ffn, toks, reference.arch_of(cfg), "float32",
+    h0 = reference.hidden(no_ffn, toks, reference.static_config(cfg), "float32",
                           "highest")
     assert not np.allclose(np.asarray(h), np.asarray(h0), atol=1e-3)
+
+
+SPARSE_FAMILY = '''
+import jax
+import jax.numpy as jnp
+
+
+def arch_config(cfg):
+    from repro.models.model import ArchConfig
+    h, f = cfg["num_attention_heads"], cfg["moe_intermediate_size"]
+    return ArchConfig(
+        name=cfg["name"], family="moe", n_layers=cfg["num_hidden_layers"],
+        d_model=cfg["hidden_size"], n_heads=h,
+        n_kv_heads=cfg["num_key_value_heads"], d_ff=f,
+        vocab=cfg["vocab_size"], n_experts=cfg["num_experts"],
+        top_k=cfg["num_experts_per_tok"], d_expert=f, qkv_bias=True,
+        rope_theta=float(cfg["rope_theta"]))
+
+
+def shapes(cfg):
+    d, f, e = (cfg["hidden_size"], cfg["moe_intermediate_size"],
+               cfg["num_experts"])
+    h, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    hd, v, n = d // h, cfg["vocab_size"], cfg["num_hidden_layers"]
+    return {
+        "embed": (v, d), "lm_head": (v, d), "ln_f": {"scale": (d,)},
+        "layers": {
+            "ln1": {"scale": (n, d)}, "ln2": {"scale": (n, d)},
+            "attn": {"wq": (n, d, h * hd), "wk": (n, d, kv * hd),
+                     "wv": (n, d, kv * hd), "wo": (n, h * hd, d),
+                     "bq": (n, h * hd), "bk": (n, kv * hd),
+                     "bv": (n, kv * hd)},
+            "moe": {"router": (n, d, e), "w_gate": (n, e, d, f),
+                    "w_up": (n, e, d, f), "w_down": (n, e, f, d)}}}
+
+
+def ffn(lp, rows, mm, cfg):
+    """Softmax router over every expert, the top ``num_experts_per_tok``
+    kept, renormalised only where ``norm_topk_prob`` says so."""
+    m = lp["moe"]
+    probs = jax.nn.softmax(
+        mm("sd,de->se", rows, m["router"]).astype(jnp.float32), -1)
+    top, idx = jax.lax.top_k(probs, cfg["num_experts_per_tok"])
+    if cfg["norm_topk_prob"]:
+        top = top / top.sum(-1, keepdims=True)
+    gate = jnp.sum(jax.nn.one_hot(idx, probs.shape[-1]) * top[..., None], 1)
+    h = (jax.nn.silu(mm("sd,edf->sef", rows, m["w_gate"]))
+         * mm("sd,edf->sef", rows, m["w_up"]))
+    return mm("se,sed->sd", gate.astype(rows.dtype),
+              mm("sef,efd->sed", h, m["w_down"]))
+
+
+def ffn_flops_per_token(cfg):
+    d, f = cfg["hidden_size"], cfg["moe_intermediate_size"]
+    return 2.0 * cfg["num_hidden_layers"] * (
+        d * cfg["num_experts"] + cfg["num_experts_per_tok"] * 3 * d * f)
+'''
+
+
+@pytest.fixture
+def sparse_family(tmp_path, monkeypatch):
+    """A sparse-expert family whose feed-forward takes its experts per
+    token and its gate's normalisation from keys of the configuration,
+    written into ``tmp_path``; no existing file of the benchmark changes."""
+    (tmp_path / "toy_sparse.py").write_text(SPARSE_FAMILY)
+    monkeypatch.setattr(families, "load",
+                        functools.partial(families.load, base_dir=tmp_path))
+    cfg = spec.load_json(DATA / "tiny.json")
+    cfg.update(model_type="toy_sparse", num_experts=4, num_experts_per_tok=1,
+               moe_intermediate_size=64, norm_topk_prob=False)
+    return cfg
+
+
+def test_a_family_reads_its_configuration(sparse_family):
+    """Two configurations that differ only in experts per token get
+    different engine configs, operation counts and reference hidden
+    states; weights and the bf16 control run through the shared callers."""
+    one = sparse_family
+    two = dict(one, num_experts_per_tok=2)
+    n, d, e, f = (one["num_hidden_layers"], one["hidden_size"],
+                  one["num_experts"], one["moe_intermediate_size"])
+    assert harness.arch_config(one).top_k == 1
+    assert harness.arch_config(two).top_k == 2
+    assert flops.arch(two)["ffn"] - flops.arch(one)["ffn"] == 2.0 * n * 3 * d * f
+
+    params = weights.make_params(one, record_golden.SEED)
+    moe = params["layers"]["moe"]
+    assert moe["router"].shape == (n, d, e)
+    assert moe["w_down"].shape == (n, e, f, d)
+
+    prompt, served = record_golden.request(one["vocab_size"])
+    toks = record_golden.padded(prompt, served, reference.SEQ_STEP)
+    h1, h2 = (np.asarray(reference.hidden(
+        params, toks, reference.static_config(c), "float32", "highest"))
+        for c in (one, two))
+    assert np.all(np.isfinite(h1)) and np.all(np.isfinite(h2))
+    assert not np.allclose(h1, h2, atol=1e-3)
+
+    for cfg in (one, two):
+        ctrl = reference.control_tokens(params, cfg, prompt, served)
+        r = reference.readings(params, cfg, prompt, served, [ctrl])
+        assert len(r["gap"]) == len(served) and np.all(r["gap"] >= 0)
+        assert np.all(np.isfinite(r["extra_gaps"][0]))
+
+
+def test_reference_compiles_once_per_configuration(sparse_family):
+    """Another request of the same padded length reuses the compiled
+    reference; another configuration compiles its own."""
+    cfg = sparse_family
+    params = weights.make_params(cfg, record_golden.SEED)
+    rng = np.random.default_rng(1)
+
+    def run(c):
+        prompt = rng.integers(1, c["vocab_size"], 100).astype(np.int32)
+        served = rng.integers(1, c["vocab_size"], 20).astype(np.int32)
+        reference.readings(params, c, prompt, served)
+
+    run(cfg)
+    size = reference.hidden._cache_size()
+    run(cfg)
+    assert reference.hidden._cache_size() == size
+    run(dict(cfg, norm_topk_prob=True))
+    assert reference.hidden._cache_size() == size + 1
 
 
 @pytest.mark.parametrize("model_type", [None, "no_such_family"])
