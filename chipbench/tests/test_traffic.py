@@ -1,14 +1,16 @@
+import json
 from pathlib import Path
 
 import numpy as np
 import pytest
 
 from chipbench import traffic
-from chipbench.spec import load_traffic
+from chipbench.spec import ROOT, load_traffic
 
 DATA = Path(__file__).resolve().parent / "data"
-MIXES = ["industrial3p-over", "gamma-mix"]
+MIXES = ["industrial3p-over", "industrial3p-flood", "gamma-mix"]
 BIG = 2**31 + 987654321
+BEST_SERVED = 231.75      # output tokens/s: the most this model has served
 
 
 def _mix(name):
@@ -84,3 +86,22 @@ def test_ramp_precedes_the_window():
     m = load_traffic("industrial3p-over")
     reqs = traffic.build(m, 45, 3, 1000, rate=1.0)
     assert any(r.due < 0 for r in reqs) and any(r.due >= 0 for r in reqs)
+
+
+def test_flood_is_the_paper_mix_offered_harder():
+    over = load_traffic("industrial3p-over")
+    flood = load_traffic("industrial3p-flood")
+    rate_keys = {"rate_rps", "rate_share_of_knee", "knee_rps", "notes"}
+    assert {k for k in over.keys() | flood.keys()
+            if over.get(k) != flood.get(k)} == rate_keys | {"name"}
+    assert flood["rate_rps"] == pytest.approx(
+        flood["knee_rps"] * flood["rate_share_of_knee"])
+
+
+def test_flood_offers_several_times_the_best_served_rate():
+    """Output tokens offered over ramp and window, whatever the seed."""
+    m = load_traffic("industrial3p-flood")
+    window = json.loads((ROOT / "BENCHMARK.json").read_text())["run_seconds"]
+    reqs = traffic.build(m, window, BIG, 1000)
+    offered = sum(r.output_len for r in reqs) / (m["ramp_s"] + window)
+    assert offered >= 3 * BEST_SERVED
